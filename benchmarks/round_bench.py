@@ -305,9 +305,10 @@ def _population_child(n_clients: int, rounds: int, cache: int) -> dict:
     }
 
 
-def _bench_population(dry: bool):
-    """Spawn one subprocess per population size (fresh ru_maxrss each)
-    and collect the per-size rows."""
+def bench_population(dry: bool):
+    """One subprocess per population size (fresh ru_maxrss each) ->
+    the per-size rows. Call it before this process touches the device:
+    on a TPU each child needs the chip for itself."""
     sizes = POPULATION_SIZES_DRY if dry else POPULATION_SIZES
     rounds, cache = (3, 32) if dry else (20, 64)
     env = dict(os.environ)
@@ -350,14 +351,21 @@ def _summarize_population(pop_rows):
 
 
 def run(*, dry: bool = False, reps: int = 10, algo_name: str = "fomaml",
-        json_out: str = "results/bench/BENCH_round.json"):
+        json_out: str = "results/bench/BENCH_round.json", pop_rows=None):
+    """All sections -> the report. `pop_rows` takes the population
+    section from a caller that ran `bench_population` before touching
+    the device; without it that section runs first."""
     import jax
 
     from repro.core.fedmeta import (init_packed_state, make_meta_train_step,
                                     make_packed_meta_train_step)
     from repro.optim import adam
+    from repro.sharding.context import make_mesh
     from repro.utils.flat import plane_for
     from repro.utils.pytree import tree_size
+
+    if pop_rows is None:
+        pop_rows = bench_population(dry)
 
     scales = ["tiny"] if dry else ["small", "large"]
     m = 4 if dry else CLIENTS
@@ -367,7 +375,7 @@ def run(*, dry: bool = False, reps: int = 10, algo_name: str = "fomaml",
         [("vmap", None), ("scan", None), ("chunked", 4), ("sharded", None)]
 
     n_dev = jax.device_count()
-    mesh = jax.make_mesh((n_dev,), ("clients",))
+    mesh = make_mesh((n_dev,), ("clients",))
 
     rows = []
     for scale in scales:
@@ -421,7 +429,6 @@ def run(*, dry: bool = False, reps: int = 10, algo_name: str = "fomaml",
                               reps=1 if dry else 2)
     comm_rows = _bench_comm("tiny" if dry else "large",
                             reps=1 if dry else 2)
-    pop_rows = _bench_population(dry)
 
     report = {
         "bench": "round",
@@ -447,7 +454,7 @@ def run(*, dry: bool = False, reps: int = 10, algo_name: str = "fomaml",
 def run_population_only(*, dry: bool = False, json_out: str):
     """Run just the population section and merge it into an existing
     report (the other sections' committed numbers are left untouched)."""
-    return _run_section_only("population_rows", _bench_population(dry),
+    return _run_section_only("population_rows", bench_population(dry),
                              _summarize_population, dry=dry,
                              json_out=json_out)
 

@@ -88,6 +88,13 @@ def main() -> None:
     rounds = args.rounds or (400 if args.full else 120)
 
     print("name,us_per_call,derived", flush=True)
+    pop_rows = None
+    if "round" in only:
+        # the round bench's population children each need the device
+        # for themselves, so they run before this process touches it
+        from benchmarks import round_bench
+        pop_rows = round_bench.bench_population(dry=not args.full)
+
     if "kernels" in only:
         for name, us, derived in _bench_kernels():
             print(f"{name},{us:.1f},{derived}", flush=True)
@@ -112,7 +119,8 @@ def main() -> None:
         out = (os.path.join(args.outdir, "BENCH_round.json")
                if args.full
                else os.path.join(smoke_dir, "BENCH_round.json"))
-        report = round_bench.run(dry=not args.full, json_out=out)
+        report = round_bench.run(dry=not args.full, json_out=out,
+                                 pop_rows=pop_rows)
         spd = report["summary"].get("round_speedup_client_plane_vs_packed")
         aspd = report["summary"].get("async_speedup")
         print(f"round,{(time.time()-t0)*1e6:.0f},"

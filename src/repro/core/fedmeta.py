@@ -39,11 +39,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.meta_update import ops as mu_ops
-from repro.sharding.context import get_mesh
+from repro.sharding.context import get_mesh, make_mesh
 from repro.utils.flat import FlatPlane, plane_for
 from repro.utils.pytree import tree_add, tree_scale, tree_zeros_like
 
@@ -91,7 +90,7 @@ def _resolve_mesh(mesh, mesh_axis):
     device placement."""
     mesh = mesh or get_mesh()
     if mesh is None:
-        mesh = jax.make_mesh((jax.device_count(),), ("clients",))
+        mesh = make_mesh((jax.device_count(),), ("clients",))
     return mesh, (mesh_axis or mesh.axis_names[0])
 
 
@@ -144,9 +143,9 @@ def _sharded_reduce(chunk_fn, acc0, add, support, query, w, m, client_chunk,
             lambda x: jax.lax.psum(x, ax), t)
         return psum(partial), psum(pm)
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=msh, in_specs=(P(ax), P(ax), P(ax)),
-        out_specs=(P(), P()), check_rep=False)(sup_p, qry_p, w_p)
+        out_specs=(P(), P()), check_vma=False)(sup_p, qry_p, w_p)
 
 
 def federated_meta_step(algo, optimizer, phi, opt_state, support, query,
@@ -203,11 +202,9 @@ def federated_meta_step(algo, optimizer, phi, opt_state, support, query,
 def _maybe_jit(step, jit: bool, donate: bool):
     if not jit:
         return step
-    # buffer donation lets φ/opt-state update in place; XLA:CPU does not
-    # implement donation and would warn on every call, so gate on backend
-    if donate and jax.default_backend() != "cpu":
-        return jax.jit(step, donate_argnums=(0,))
-    return jax.jit(step)
+    # buffer donation lets φ/opt-state update in place: the caller's
+    # state is consumed by the call and must not be read again
+    return jax.jit(step, donate_argnums=(0,) if donate else ())
 
 
 def make_meta_train_step(algo, optimizer, *, client_axis: str = "vmap",
@@ -292,8 +289,8 @@ def make_packed_meta_train_step(algo, optimizer, plane: FlatPlane, *,
     needs structured parameters); everything after the per-client grads —
     aggregation and the outer Adam — stays on flat buffers. ``impl``
     picks xla / pallas / pallas_interpret for the fused kernels (None =
-    the ``REPRO_META_UPDATE_IMPL`` default). ``block_dtype`` sets the
-    dtype of the packed client-gradient block (None = f32, exact;
+    the platform's pick, ``kernels/dispatch.py``). ``block_dtype`` sets
+    the dtype of the packed client-gradient block (None = f32, exact;
     bfloat16 halves the aggregation traffic and models a half-precision
     client upload — the fused ops still accumulate in f32; see
     DESIGN.md §2).
@@ -414,18 +411,33 @@ def make_packed_meta_train_step(algo, optimizer, plane: FlatPlane, *,
 
     def finish(state, meta_g, metrics, extra=None):
         """Outer optimizer step + optional non-finite guard."""
-        new_flat, new_opt = flat_opt.update(state["phi"], meta_g,
-                                            state["opt"])
+        def update():
+            if client_axis != "sharded":
+                return flat_opt.update(state["phi"], meta_g, state["opt"])
+            # φ and its optimizer state are replicated over the client
+            # mesh, and a Pallas (Mosaic) kernel has no partitioning
+            # rule: every device runs the fused update on its replica
+            msh, _ = _resolve_mesh(mesh, mesh_axis)
+            return jax.shard_map(
+                flat_opt.update, mesh=msh, in_specs=P(), out_specs=P(),
+                check_vma=False)(state["phi"], meta_g, state["opt"])
+
         if guard:
             # one fused reduce over the flat plane; skip-and-log round
             # semantics: a non-finite meta-gradient leaves φ AND the
-            # optimizer state (incl. Adam's step count) untouched
+            # optimizer state (incl. Adam's step count) untouched. A
+            # cond, not a select over the outputs: the update then
+            # compiles as in the unguarded step, so a clean run stays
+            # bitwise identical (with a select, XLA recomputes the
+            # moment update inside the φ fusion, which can round 1 ulp
+            # differently)
             ok = jnp.all(jnp.isfinite(meta_g))
-            new_flat = jnp.where(ok, new_flat, state["phi"])
-            new_opt = jax.tree.map(
-                lambda n, o: jnp.where(ok, n, o), new_opt, state["opt"])
+            new_flat, new_opt = jax.lax.cond(
+                ok, update, lambda: (state["phi"], state["opt"]))
             metrics = {**metrics,
                        "skipped": jnp.logical_not(ok).astype(jnp.float32)}
+        else:
+            new_flat, new_opt = update()
         new_state = {"phi": new_flat, "opt": new_opt}
         if extra is not None:
             new_state.update(extra)

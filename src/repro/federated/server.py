@@ -13,8 +13,9 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.fedmeta import (_maybe_jit, init_packed_state,
+from repro.core.fedmeta import (_maybe_jit, _resolve_mesh, init_packed_state,
                                 make_meta_train_step,
                                 make_packed_meta_train_step)
 from repro.data.federated import (TaskStream, assemble_task_batch,
@@ -357,6 +358,36 @@ class FederatedTrainer:
             self.comm.flops_per_client = fl
         return fl
 
+    def placement(self) -> Callable:
+        """The ``device_put`` that stages round inputs. On the sharded
+        client axis every chip receives its own clients' rows straight
+        from the host — split along the leading client axis when the
+        mesh axis divides it — instead of all rows landing on the
+        default device and crossing to the others inside the step.
+        Elsewhere: the default device."""
+        if self.client_axis != "sharded" or self.fuse_rounds > 1:
+            return jax.device_put
+        mesh, ax = _resolve_mesh(self.mesh, self.mesh_axis)
+        n = mesh.shape[ax]
+        sharding = NamedSharding(mesh, P(ax))
+
+        def put(x):
+            if np.ndim(x) and np.shape(x)[0] % n == 0:
+                return jax.device_put(x, sharding)
+            return jax.device_put(x)
+        return put
+
+    def _place_state(self, state):
+        """Put a train state where the step returns it. On the sharded
+        client axis that is replicated over the client mesh: a state
+        left on one device (fresh from ``init`` or ``resume``) would
+        have the step compile once for it and again for the replicated
+        state it returns. Elsewhere the state is left as it is."""
+        if self.client_axis != "sharded":
+            return state
+        mesh, _ = _resolve_mesh(self.mesh, self.mesh_axis)
+        return jax.device_put(state, NamedSharding(mesh, P()))
+
     def _stage_block(self, stream, dp, k, round_):
         """Host half of one round block: sample + device_put staging.
         Runs on the prefetch thread (in block order) when pipelined.
@@ -559,7 +590,8 @@ class FederatedTrainer:
         stream = TaskStream(self.train_clients, self.clients_per_round,
                             self.support_frac, self.support_size,
                             self.query_size, self._rng)
-        dp = jax.device_put
+        state = self._place_state(state)
+        dp = self.placement()
         produced = {"r": start_round}   # prefetch-thread round cursor
         if self.pool_workers > 0:
             clients = self.train_clients

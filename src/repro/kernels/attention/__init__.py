@@ -1,1 +1,1 @@
-from repro.kernels.attention.ops import flash_attention, set_default_impl
+from repro.kernels.attention.ops import flash_attention, use_impl

@@ -7,31 +7,28 @@ impl:
   "xla"              — pure-jnp reference (CPU tests, dry-run lowering)
   "pallas_interpret" — Pallas kernel, interpret mode (CPU correctness)
   "pallas"           — Pallas kernel compiled for TPU (production)
-Default comes from REPRO_ATTN_IMPL env var, else "xla".
+Unset, the platform picks (``kernels/dispatch.py``). The kernel has no
+backward: a caller that differentiates through attention pins "xla"
+with ``use_impl`` (the LM loss does, ``launch/steps.make_apply_fn``).
 """
 from __future__ import annotations
-
-import os
 
 import jax.numpy as jnp
 
 from repro.kernels.attention import ref
 from repro.kernels.attention.flash_attention import flash_attention_bhld
+from repro.kernels.dispatch import ImplChoice
 
-_DEFAULT_IMPL = os.environ.get("REPRO_ATTN_IMPL", "xla")
-
-
-def set_default_impl(impl: str) -> None:
-    global _DEFAULT_IMPL
-    assert impl in ("xla", "pallas", "pallas_interpret")
-    _DEFAULT_IMPL = impl
+_IMPL = ImplChoice("attention")
+resolve_impl = _IMPL.resolve
+use_impl = _IMPL.use
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
                     q_offset: int = 0, kv_length=None, impl: str | None = None,
                     block_q: int = 128, block_k: int = 128):
     """q: (B, Lq, H, hd); k, v: (B, Lk, Kv, hd) -> (B, Lq, H, hd)."""
-    impl = impl or _DEFAULT_IMPL
+    impl = resolve_impl(impl)
     if impl == "xla" or kv_length is not None:
         # variable kv_length (ragged decode) stays on the XLA path
         return ref.mha_reference(q, k, v, causal=causal, window=window,

@@ -28,6 +28,7 @@ def _flash_decode_kernel(kvl_ref, q_ref, k_ref, v_ref, o_ref,
                          m_scr, l_scr, acc_scr, *, bk: int, nc: int,
                          scale: float):
     ci = pl.program_id(2)
+    kvl = kvl_ref[pl.program_id(0)]                  # () valid length
 
     @pl.when(ci == 0)
     def _init():
@@ -38,7 +39,6 @@ def _flash_decode_kernel(kvl_ref, q_ref, k_ref, v_ref, o_ref,
     q = q_ref[0, 0].astype(jnp.float32)              # (G, hd)
     k = k_ref[0, 0].astype(jnp.float32)              # (bk, hd)
     v = v_ref[0, 0].astype(jnp.float32)              # (bk, hd)
-    kvl = kvl_ref[0]                                 # () valid length
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -82,22 +82,29 @@ def flash_decode(q, k_cache, v_cache, kv_length, *, block_k: int = 512,
 
     kernel = functools.partial(_flash_decode_kernel, bk=bk, nc=nc,
                                scale=1.0 / (hd ** 0.5))
-    out = pl.pallas_call(
-        kernel,
+    # kv_length rides in SMEM as a scalar-prefetch operand (the index
+    # maps receive it after the grid indices): a rank-1 VMEM block of
+    # one int per batch row is not a legal TPU tile once B > 1
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, Kv, nc),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, c: (b,)),
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, c: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, G, hd), lambda b, h, c, kvl: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bk, hd), lambda b, h, c, kvl: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, bk, hd), lambda b, h, c, kvl: (b, h, c, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, c: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Kv, G, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, hd),
+                               lambda b, h, c, kvl: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 128), jnp.float32),
             pltpu.VMEM((G, 128), jnp.float32),
             pltpu.VMEM((G, hd), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Kv, G, hd), q.dtype),
         interpret=interpret,
     )(kv_length.astype(jnp.int32), qt, kt, vt)
     return out.reshape(B, H, hd)

@@ -1,44 +1,27 @@
 """Dispatcher for one-token decode attention.
 
-impl: "xla" (oracle; default), "pallas", "pallas_interpret".
+impl: "xla" (oracle), "pallas", "pallas_interpret"; unset, the platform
+picks (``kernels/dispatch.py``). ``use_impl`` scopes a pin — baked in at
+*trace* time: wrap the first call of a jitted serve fn, not later
+replays of an already-compiled executable.
 """
 from __future__ import annotations
-
-import contextlib
-import os
 
 import jax.numpy as jnp
 
 from repro.kernels.decode_attention import ref
 from repro.kernels.decode_attention.flash_decode import flash_decode
+from repro.kernels.dispatch import ImplChoice
 
-_DEFAULT_IMPL = os.environ.get("REPRO_DECODE_ATTN_IMPL", "xla")
-
-
-def set_default_impl(impl: str) -> None:
-    global _DEFAULT_IMPL
-    assert impl in ("xla", "pallas", "pallas_interpret")
-    _DEFAULT_IMPL = impl
-
-
-@contextlib.contextmanager
-def use_impl(impl: str):
-    """Scoped default-impl override (restores on exit). The impl is
-    baked in at *trace* time: wrap the first call of a jitted serve
-    fn, not later replays of an already-compiled executable."""
-    global _DEFAULT_IMPL
-    prev = _DEFAULT_IMPL
-    set_default_impl(impl)
-    try:
-        yield
-    finally:
-        _DEFAULT_IMPL = prev
+_IMPL = ImplChoice("decode_attention")
+resolve_impl = _IMPL.resolve
+use_impl = _IMPL.use
 
 
 def decode_attention(q, k_cache, v_cache, kv_length, *, impl=None,
                      block_k: int = 512):
     """q: (B, H, hd); caches: (B, C, Kv, hd); kv_length: () or (B,)."""
-    impl = impl or _DEFAULT_IMPL
+    impl = resolve_impl(impl)
     kvl = jnp.broadcast_to(jnp.asarray(kv_length), (q.shape[0],))
     if impl == "xla":
         return ref.decode_attention_ref(q, k_cache, v_cache, kvl)

@@ -1,3 +1,3 @@
-from repro.kernels.meta_update.ops import (get_default_impl, inner_update,
-                                           meta_update, set_default_impl,
+from repro.kernels.meta_update.ops import (inner_update, meta_update,
+                                           resolve_impl, use_impl,
                                            weighted_aggregate)

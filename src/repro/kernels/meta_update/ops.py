@@ -1,9 +1,9 @@
 """Dispatchers for the fused meta-step ops.
 
-impl: "xla" (tree_map / jnp; default), "pallas", "pallas_interpret",
-selected per-call, via :func:`set_default_impl`, or the
-``REPRO_META_UPDATE_IMPL`` environment variable (see DESIGN.md §5).
-One switch governs all three fused ops — inner update, weighted
+impl: "xla" (tree_map / jnp), "pallas", "pallas_interpret", selected
+per call or scoped with :func:`use_impl`; unset, the platform picks
+("pallas" on TPU, "xla" elsewhere — ``kernels/dispatch.py``, DESIGN.md
+§5). One switch governs all three fused ops — inner update, weighted
 aggregation, outer Adam — so a config flips the whole pipeline.
 
 The pallas paths run on the packed parameter plane (``utils/flat.py``):
@@ -13,10 +13,9 @@ inside every client of every round — never recompute the layout.
 """
 from __future__ import annotations
 
-import os
-
 import jax.numpy as jnp
 
+from repro.kernels.dispatch import ImplChoice
 from repro.kernels.meta_update import ref
 from repro.kernels.meta_update.compress import (CODECS,  # noqa: F401
                                                 CompressionConfig,
@@ -40,24 +39,9 @@ from repro.kernels.meta_update.fused import (TILE,  # noqa: F401 (re-export)
                                              meta_update_flat)
 from repro.utils.flat import plane_for
 
-_DEFAULT_IMPL = os.environ.get("REPRO_META_UPDATE_IMPL", "xla")
-_IMPLS = ("xla", "pallas", "pallas_interpret")
-
-
-def set_default_impl(impl: str) -> None:
-    global _DEFAULT_IMPL
-    assert impl in _IMPLS
-    _DEFAULT_IMPL = impl
-
-
-def get_default_impl() -> str:
-    return _DEFAULT_IMPL
-
-
-def resolve_impl(impl: str | None) -> str:
-    impl = impl or _DEFAULT_IMPL
-    assert impl in _IMPLS, impl
-    return impl
+_IMPL = ImplChoice("meta_update")
+resolve_impl = _IMPL.resolve
+use_impl = _IMPL.use
 
 
 def meta_update(theta, alpha, grads, *, impl: str | None = None):

@@ -1,26 +1,23 @@
 """Jitted wrapper for the SSD scan: Pallas intra-chunk kernel + XLA
 inter-chunk recurrence, with the pure-jnp chunked oracle as fallback.
 
-impl: "xla" (default; used on CPU and in the dry-run), "pallas",
-"pallas_interpret". Default from REPRO_SSD_IMPL env var.
+impl: "xla", "pallas", "pallas_interpret"; unset, the platform picks
+(``kernels/dispatch.py``). The kernel has no backward: a caller that
+differentiates through the scan pins "xla" with ``use_impl`` (the LM
+loss does, ``launch/steps.make_apply_fn``).
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.dispatch import ImplChoice
 from repro.kernels.ssd import ref
 from repro.kernels.ssd.ssd_scan import ssd_intra_chunk_pallas
 
-_DEFAULT_IMPL = os.environ.get("REPRO_SSD_IMPL", "xla")
-
-
-def set_default_impl(impl: str) -> None:
-    global _DEFAULT_IMPL
-    assert impl in ("xla", "pallas", "pallas_interpret")
-    _DEFAULT_IMPL = impl
+_IMPL = ImplChoice("ssd")
+resolve_impl = _IMPL.resolve
+use_impl = _IMPL.use
 
 
 def ssd_chunked(x, dt, A, Bm, Cm, *, chunk: int, impl: str | None = None,
@@ -29,7 +26,7 @@ def ssd_chunked(x, dt, A, Bm, Cm, *, chunk: int, impl: str | None = None,
 
     With return_final_state, also returns the (B, nh, hp, N) state after
     the last token (for prefill -> decode handoff)."""
-    impl = impl or _DEFAULT_IMPL
+    impl = resolve_impl(impl)
     if impl == "xla":
         return ref.ssd_chunked_ref(x, dt, A, Bm, Cm, chunk,
                                    return_final_state=return_final_state)

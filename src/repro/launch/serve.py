@@ -1,8 +1,12 @@
-"""Personalized serving launcher: prefill + batched decode on the
-production mesh (or --reduced on CPU), plus the builders that wire an
-LM config into `federated.serving.ServingEngine` (adaptation-on-demand,
-DESIGN.md §18).
+"""Personalized serving launcher: batched decode on the chips present
+(or --reduced on CPU), plus the builders that wire an LM config into
+`federated.serving.ServingEngine` (adaptation-on-demand, DESIGN.md §18).
 
+Without --reduced the decode batch is each chip's share of the shape's
+global batch, as on the production pod's 16-way data axis.
+
+  PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m \
+      --shape decode_32k --steps 4
   PYTHONPATH=src python -m repro.launch.serve --arch granite-3-2b \
       --shape decode_32k --steps 4 --reduced
 """
@@ -19,7 +23,8 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import INPUT_SHAPES, get_config, list_archs, reduced_config
-from repro.launch.mesh import make_host_mesh, make_production_mesh
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import PRODUCTION_DATA, make_device_mesh
 from repro.launch.steps import (input_specs, make_apply_fn, make_decode_step,
                                 make_prefill_step, resolve_serving_config)
 from repro.models import init_lm
@@ -75,20 +80,29 @@ def main():
     ap.add_argument("--arch", required=True, choices=list_archs())
     ap.add_argument("--shape", default="decode_32k")
     ap.add_argument("--steps", type=int, default=4)
-    ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--multi-pod", action="store_true")
+    ap.add_argument("--reduced", action="store_true",
+                    help="reduced config + small shape (CPU execution)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     shape = INPUT_SHAPES[args.shape]
-    assert shape.kind == "decode"
+    if shape.kind != "decode":
+        raise SystemExit("use train.py for train shapes")
 
     if args.reduced:
         cfg = reduced_config(cfg)
         shape = dataclasses.replace(shape, seq_len=128, global_batch=2)
-        mesh = make_host_mesh(1, 1)
+        mesh = make_device_mesh(jax.devices()[:1])
     else:
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
+        mesh = make_device_mesh()
+        n_data = mesh.devices.shape[0]
+        per_chip = max(1, shape.global_batch // PRODUCTION_DATA)
+        print(f"cut: {shape.name} on {n_data} chip(s): batch "
+              f"{per_chip * n_data} (of {shape.global_batch}; {per_chip} "
+              f"per chip on the production data axis) x "
+              f"{shape.seq_len}-token cache", flush=True)
+        shape = dataclasses.replace(shape, global_batch=per_chip * n_data)
 
     spec = input_specs(cfg, shape, mesh)
     scfg = spec["serving_cfg"]
@@ -103,20 +117,21 @@ def main():
                    out_shardings=(None, nm(spec["pspec"]["cache"])),
                    donate_argnums=(1,))
 
-    with mesh:
-        params = jax.jit(lambda k: init_lm(k, scfg),
-                         out_shardings=nm(pspec))(jax.random.PRNGKey(0))
-        cache = jax.tree.map(
-            lambda s: jnp.zeros(s.shape, s.dtype), spec["batch"]["cache"])
-        cache["length"] = jnp.asarray(min(64, shape.seq_len), jnp.int32)
-        tok = jnp.zeros((shape.global_batch, 1), jnp.int32)
-        for it in range(args.steps):
-            t0 = time.perf_counter()
-            logits, cache = step(params, cache, tok)
-            jax.block_until_ready(logits)
-            tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-            print(f"decode step {it}: {time.perf_counter()-t0:.2f}s  "
-                  f"logits {logits.shape}", flush=True)
+    params = jax.jit(lambda k: init_lm(k, scfg),
+                     out_shardings=nm(pspec))(jax.random.PRNGKey(0))
+    cache = jax.jit(
+        lambda: jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                             spec["batch"]["cache"]),
+        out_shardings=nm(spec["pspec"]["cache"]))()
+    cache["length"] = jnp.asarray(min(64, shape.seq_len), jnp.int32)
+    tok = jnp.zeros((shape.global_batch, 1), jnp.int32)
+    for it in range(args.steps):
+        t0 = time.perf_counter()
+        logits, cache = step(params, cache, tok)
+        jax.block_until_ready(logits)
+        tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+        print(f"decode step {it}: {time.perf_counter()-t0:.2f}s  "
+              f"logits {logits.shape}", flush=True)
 
 
 if __name__ == "__main__":

@@ -23,6 +23,9 @@ from repro.configs import INPUT_SHAPES, InputShape, ModelConfig
 from repro.core.algorithms import make_algorithm
 from repro.core.fedmeta import federated_meta_step
 from repro.core.losses import lm_loss
+from repro.kernels.attention import ops as attn_ops
+from repro.kernels.meta_update import ops as mu_ops
+from repro.kernels.ssd import ops as ssd_ops
 from repro.models import init_lm, lm_apply, init_decode_cache, lm_decode_step
 from repro.optim import Optimizer, adam
 from repro.sharding.rules import (batch_axes, batch_pspec, cache_pspecs,
@@ -43,15 +46,22 @@ def resolve_serving_config(cfg: ModelConfig, shape: InputShape) -> ModelConfig:
 
 def make_apply_fn(cfg: ModelConfig, *, remat: bool = True,
                   unroll_layers: bool = False):
-    """apply(params, batch) -> (logits, aux); batch = tokens or dict."""
+    """apply(params, batch) -> (logits, aux); batch = tokens or dict.
+
+    This is the forward of the LM loss, which training and adaptation
+    differentiate. The flash-attention and SSD kernels have no backward,
+    so attention and the SSD scan are pinned to their XLA paths here,
+    whatever the platform (serving's prefill and decode keep the
+    kernels)."""
 
     def apply_fn(params, batch):
-        if isinstance(batch, dict):
-            return lm_apply(params, cfg, batch["tokens"],
-                            modality_embeds=batch.get("embeds"), remat=remat,
+        with attn_ops.use_impl("xla"), ssd_ops.use_impl("xla"):
+            if isinstance(batch, dict):
+                return lm_apply(params, cfg, batch["tokens"],
+                                modality_embeds=batch.get("embeds"),
+                                remat=remat, unroll_layers=unroll_layers)
+            return lm_apply(params, cfg, batch, remat=remat,
                             unroll_layers=unroll_layers)
-        return lm_apply(params, cfg, batch, remat=remat,
-                        unroll_layers=unroll_layers)
 
     return apply_fn
 
@@ -81,6 +91,17 @@ def make_train_step(cfg: ModelConfig, *, algo_name: str = "fomaml",
         return {"phi": phi, "opt": optimizer.init(phi)}
 
     def train_step(state, batch):
+        # the tree inner update stays on XLA: the Pallas path packs all
+        # of θ into an f32 plane every inner step, which for
+        # smollm-360m on a v5e costs 1.7 GB more temp (9.3 -> 11.0 GB)
+        # and 8x the compile time (21 -> 173 s) for one elementwise pass
+        # that XLA already fuses per leaf. Both figures come from
+        # ahead-of-time compiles only; the two paths' step times on the
+        # chip have not been compared
+        with mu_ops.use_impl("xla"):
+            return _train_step(state, batch)
+
+    def _train_step(state, batch):
         def per_group(sup, qry):
             # scan over clients with a meta-gradient accumulator: only one
             # adapted θ_u is live at a time (DESIGN.md §4)

@@ -274,26 +274,32 @@ def lm_decode_step(params, cfg, tokens, cache, *, unroll_layers: bool = False):
             params[f"lead_{i}"], cfg, spec, x, cache[f"lead_{i}"], length,
             enc_out=enc_out)
 
-    def body(h, inp):
-        rep_params, rep_caches = inp
-        out_caches = {}
+    # The stacked cache rides the layer loop as a carry and each layer
+    # writes its slice back with dynamic_update_slice, so a donated
+    # cache is updated in place. (As scan input and output it would be
+    # a second full cache beside the first.)
+    def body(r, carry):
+        h, stack = carry
         for j, spec in enumerate(period):
-            h, out_caches[f"pos{j}"] = block_decode(
-                rep_params[f"pos{j}"], cfg, spec, h, rep_caches[f"pos{j}"],
-                length, enc_out=enc_out)
-        return h, out_caches
+            layer = jax.tree.map(
+                lambda c: jax.lax.dynamic_index_in_dim(c, r, keepdims=False),
+                stack[f"pos{j}"])
+            rep_params = jax.tree.map(
+                lambda p: jax.lax.dynamic_index_in_dim(p, r, keepdims=False),
+                params["stack"][f"pos{j}"])
+            h, layer = block_decode(rep_params, cfg, spec, h, layer, length,
+                                    enc_out=enc_out)
+            stack = {**stack, f"pos{j}": jax.tree.map(
+                lambda c, u: jax.lax.dynamic_update_index_in_dim(c, u, r, 0),
+                stack[f"pos{j}"], layer)}
+        return h, stack
 
+    carry = (x, cache["stack"])
     if unroll_layers:
-        outs = []
         for r in range(n_reps):
-            rep = jax.tree.map(lambda p: p[r],
-                               (params["stack"], cache["stack"]))
-            x, oc = body(x, rep)
-            outs.append(oc)
-        stack_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+            carry = body(r, carry)
     else:
-        x, stack_caches = jax.lax.scan(body, x,
-                                       (params["stack"], cache["stack"]))
-    new_cache["stack"] = stack_caches
+        carry = jax.lax.fori_loop(0, n_reps, body, carry)
+    x, new_cache["stack"] = carry
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     return _logits(params, cfg, x), new_cache

@@ -27,7 +27,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import mlp_apply
@@ -98,12 +97,12 @@ def ep_moe_apply(params, cfg, x, mesh, *, capacity_factor=None):
               * y_flat[jnp.where(keep, slot, 0)], mode="drop")
         return contrib.reshape(Bl, L, d).astype(x_local.dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P("data", None, None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
-        out_specs=P("data", None, None), check_rep=False)
+        out_specs=P("data", None, None), check_vma=False)
     y = fn(x, params["w_router"], params["w_gate"], params["w_up"],
            params["w_down"])
     if cfg.num_shared_experts > 0:

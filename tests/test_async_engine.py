@@ -172,9 +172,10 @@ def test_staleness_discount_hand_check():
     gamma = cfg.discount ** cfg.delay
     num = w2[1] * g2[1] + w2[2] * g2[2] + gamma * w1[1] * g1[1]
     exp2 = num / (w2[1] + w2[2] + gamma * w1[1])
+    flat1 = np.asarray(state1["phi"])    # the step donates state1
     state2, _ = step(state1, *args(tb2), sel2)
     np.testing.assert_allclose(
-        np.asarray(state2["phi"]), np.asarray(state1["phi"]) - 0.1 * exp2,
+        np.asarray(state2["phi"]), flat1 - 0.1 * exp2,
         rtol=1e-5, atol=1e-7)
     # the new straggler (row 0 of round 2) sits in the ring buffer
     np.testing.assert_allclose(np.asarray(state2["stale"]["G"][0, 0]), g2[0],

@@ -51,9 +51,9 @@ def test_assigned_extras():
 
 def test_sharding_rules_divisibility_guard():
     """Dims that don't divide the mesh axis stay replicated."""
-    from repro.launch.mesh import make_host_mesh
+    from repro.launch.mesh import make_device_mesh
     from repro.sharding.rules import param_pspecs
-    mesh = make_host_mesh(1, 1)
+    mesh = make_device_mesh(jax.devices()[:1])
     params = {"wq": jnp.zeros((960, 960)),       # 960 % 1 == 0 -> sharded
               "embed": jnp.zeros((7, 960))}
     specs = param_pspecs(params, mesh)
@@ -66,13 +66,13 @@ def test_sharding_rules_divisibility_guard():
 def test_ep_moe_matches_tp_single_device(rng):
     """On a 1-device mesh the EP all_to_all is the identity, so EP and TP
     MoE must agree numerically (same routing, same capacity)."""
-    from repro.launch.mesh import make_host_mesh
+    from repro.launch.mesh import make_device_mesh
     from repro.models import moe as tp_moe
     from repro.models.layers import Rng
     from repro.sharding.ep_moe import ep_moe_apply
     cfg = dataclasses.replace(
         reduced_config(get_config("mixtral-8x22b")), num_shared_experts=0)
-    mesh = make_host_mesh(1, 1)
+    mesh = make_device_mesh(jax.devices()[:1])
     params = tp_moe.moe_init(Rng(jax.random.PRNGKey(0)), cfg, jnp.float32)
     x = jnp.asarray(rng.normal(0, 0.5, (2, 8, cfg.d_model)), jnp.float32)
     y_tp, _aux = tp_moe.moe_apply(params, cfg, x)
@@ -86,13 +86,13 @@ def test_launch_path_lowers_on_host_mesh(kind, rng):
     """input_specs + step builders lower on the 1-device host mesh for a
     reduced config (guards the production launch path)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.launch.mesh import make_host_mesh
+    from repro.launch.mesh import make_device_mesh
     from repro.launch.steps import (input_specs, make_decode_step,
                                     make_prefill_step, make_train_step)
     from repro.models import init_lm
     from repro.sharding.rules import param_pspecs, state_pspecs
     cfg = reduced_config(get_config("qwen2.5-3b"))
-    mesh = make_host_mesh(1, 1)
+    mesh = make_device_mesh(jax.devices()[:1])
     shape = dataclasses.replace(
         INPUT_SHAPES[{"train": "train_4k", "prefill": "prefill_32k",
                       "decode": "decode_32k"}[kind]],

@@ -10,6 +10,7 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_config, reduced_config
 from repro.kernels.decode_attention import ops as dec_ops
@@ -70,16 +71,21 @@ class TestServingFns:
             assert (rec["tokens"] < cfg.vocab_size).all()
 
     def test_use_impl_scopes_and_restores(self):
-        prev = dec_ops._DEFAULT_IMPL
+        prev = dec_ops.resolve_impl()
+        assert prev == "xla"                    # the CPU platform's pick
         with dec_ops.use_impl("pallas_interpret"):
-            assert dec_ops._DEFAULT_IMPL == "pallas_interpret"
-        assert dec_ops._DEFAULT_IMPL == prev
+            assert dec_ops.resolve_impl() == "pallas_interpret"
+            assert dec_ops.resolve_impl("xla") == "xla"   # explicit wins
+        assert dec_ops.resolve_impl() == prev
         try:
             with dec_ops.use_impl("xla"):
                 raise RuntimeError("boom")
         except RuntimeError:
             pass
-        assert dec_ops._DEFAULT_IMPL == prev    # restored on exception
+        assert dec_ops.resolve_impl() == prev   # restored on exception
+        with pytest.raises(ValueError):
+            with dec_ops.use_impl("cuda"):
+                pass
 
 
 class TestEntryPoints:
@@ -106,3 +112,35 @@ class TestEntryPoints:
             timeout=600)
         assert out.returncode == 0, out.stderr[-2000:]
         assert "decode step 1" in out.stdout
+
+    def test_launch_train_reduced(self):
+        """python -m repro.launch.train --reduced: the meta-training
+        launcher runs its device mesh end to end on a CPU."""
+        out = subprocess.run(
+            [sys.executable, "-m", "repro.launch.train", "--arch",
+             "smollm-360m", "--shape", "train_4k", "--steps", "2",
+             "--reduced"],
+            cwd=REPO, env=_env(), capture_output=True, text=True,
+            timeout=600)
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert "step    2  loss=" in out.stdout
+
+
+def test_per_chip_shape_is_a_sixteenth_of_each_client():
+    """One chip of the production data axis holds seqs_per_client/16 of
+    every client's sequences; n chips hold n such shares."""
+    from repro.configs import INPUT_SHAPES
+    from repro.launch.mesh import PRODUCTION_DATA, make_device_mesh
+    from repro.launch.train import per_chip_shape
+    full = INPUT_SHAPES["train_4k"]
+    one = per_chip_shape(full, 1)
+    assert (one.seqs_per_client, one.clients_per_round, one.seq_len) == \
+        (full.seqs_per_client // PRODUCTION_DATA, 8, 4096)
+    assert one.global_batch == 16
+    assert per_chip_shape(full, 4).seqs_per_client == 8
+    assert per_chip_shape(full, PRODUCTION_DATA) == full
+    mesh = make_device_mesh()
+    assert mesh.axis_names == ("data", "model")
+    assert mesh.devices.shape == (jax.device_count(), 1)
+    assert all(t == jax.sharding.AxisType.Auto for t in mesh.axis_types)
+

@@ -25,6 +25,7 @@ from repro.kernels.meta_update.aggregate import (weighted_aggregate_flat,
                                                  weighted_aggregate_ref)
 from repro.optim import adam, sgd
 from repro.optim.fused_adam import adam_flat_update
+from repro.sharding.context import make_mesh
 from repro.utils.flat import ALIGN, FlatPlane, plane_for
 
 
@@ -40,7 +41,7 @@ def _one_device_mesh():
     """shard_map runs unchanged on a 1-device mesh, so the sharded axis
     (padding, psum, local aggregation) is exercised on any host; the CI
     multi-device job re-runs this file with 4 forced host devices."""
-    return jax.make_mesh((jax.device_count(),), ("clients",))
+    return make_mesh((jax.device_count(),), ("clients",))
 
 
 def _make_round(rng, algo_name, m=5):
@@ -435,13 +436,98 @@ def test_adapt_packed_bit_identical(rng, algo_name, impl):
     FMA whenever it compiles the expression as one program, while the
     eager per-leaf path rounds the product first.)"""
     algo, phi, sup, qry, w = _make_round(rng, algo_name)
-    mu_ops.set_default_impl(impl)
-    try:
+    with mu_ops.use_impl(impl):
         theta_tree = algo.adapt(phi, sup[0], steps=3)
-    finally:
-        mu_ops.set_default_impl("xla")
     theta_flat = algo.adapt_packed(phi, sup[0], steps=3, impl=impl)
     for k in theta_tree:
         np.testing.assert_array_equal(np.asarray(theta_tree[k]),
                                       np.asarray(theta_flat[k]),
                                       err_msg=f"{algo_name}/{impl}/{k}")
+
+
+# ---- donation and staging ------------------------------------------------
+
+def test_packed_step_donates_state(rng):
+    """The jitted meta step consumes its state on every backend (the
+    buffers update in place), so a caller must read what it needs from
+    a state before stepping it."""
+    algo, phi, sup, qry, w = _make_round(rng, "fomaml")
+    plane = plane_for(phi)
+    opt = adam(1e-3)
+    step = make_packed_meta_train_step(algo, opt, plane)
+    state = init_packed_state(opt, plane, phi)
+    new_state, _ = step(state, sup, qry, w)
+    assert state["phi"].is_deleted()
+    assert not new_state["phi"].is_deleted()
+    keep = make_packed_meta_train_step(algo, opt, plane, donate=False)
+    state = init_packed_state(opt, plane, phi)
+    keep(state, sup, qry, w)
+    assert not state["phi"].is_deleted()
+
+
+def test_sharded_axis_stages_inputs_over_the_mesh():
+    """On the sharded client axis the trainer stages each round's
+    client-axis arrays split over the mesh (one block of clients per
+    device); other axes stage on the default device."""
+    from jax.sharding import NamedSharding
+
+    from repro.federated.server import FederatedTrainer
+    algo = make_algorithm("fomaml", quad_loss, quad_eval, inner_lr=0.1)
+    mesh = _one_device_mesh()
+    n = mesh.shape["clients"]
+    kw = dict(clients_per_round=2 * n, support_frac=0.5, support_size=2,
+              query_size=2, packed=True)
+    tr = FederatedTrainer(algo, adam(1e-3), [], client_axis="sharded",
+                          mesh=mesh, **kw)
+    staged = tr.placement()(np.zeros((2 * n, 3), np.float32))
+    assert isinstance(staged.sharding, NamedSharding)
+    assert staged.sharding.spec == jax.sharding.PartitionSpec("clients")
+    assert staged.sharding.device_set == set(mesh.devices.flat)
+    assert tr.placement()(np.float32(1.0)).shape == ()
+    assert FederatedTrainer(algo, adam(1e-3), [], **kw).placement() \
+        is jax.device_put
+
+
+@pytest.mark.parametrize("packed", [True, False], ids=["packed", "tree"])
+def test_sharded_trainer_compiles_its_step_once(packed, tmp_path):
+    """`run` puts its state where the sharded step returns it
+    (replicated over the client mesh), so later rounds replay the first
+    round's executable instead of compiling the step again — for a
+    fresh state from `init` and for one loaded by `resume`."""
+    from repro.core import classification_loss
+    from repro.data.federated import ClientData
+    from repro.federated.server import FederatedTrainer
+
+    rng = np.random.RandomState(0)
+    clients = [ClientData(rng.normal(0, 1, (12, 4)).astype(np.float32),
+                          rng.randint(0, 2, (12,)).astype(np.int64))
+               for _ in range(6)]
+
+    def model_init(key):
+        return {"w": jax.random.normal(key, (4, 2)) * 0.1,
+                "b": jnp.zeros((2,))}
+
+    loss_fn, eval_fn = classification_loss(lambda p, x: x @ p["w"] + p["b"])
+    algo = make_algorithm("fomaml", loss_fn, eval_fn, inner_lr=0.05)
+    mesh = _one_device_mesh()
+
+    def trainer():
+        return FederatedTrainer(
+            algo, adam(1e-3), clients, 2 * mesh.size, support_frac=0.5,
+            support_size=4, query_size=4, packed=packed,
+            client_plane=packed, client_axis="sharded", mesh=mesh,
+            checkpoint_every=2, checkpoint_dir=str(tmp_path))
+
+    tr = trainer()
+    state = tr.init(jax.random.PRNGKey(0), model_init)
+    state = tr.run(state, 3)
+    assert tr._step._cache_size() == 1
+    assert len(tr.history) == 3
+
+    tr = trainer()
+    tr.init(jax.random.PRNGKey(0), model_init)
+    state, start = tr.resume()
+    assert start == 2
+    tr.run(state, 4, start_round=start)
+    assert tr._step._cache_size() == 1
+    assert len(tr.history) == 4
