@@ -47,6 +47,8 @@ from typing import Callable, Optional
 import jax
 import numpy as np
 
+from repro.utils.trace import compiles, span
+
 PREFETCH_THREAD_NAME = "repro-round-prefetch"
 WORKER_THREAD_NAME = "repro-pool-worker"
 
@@ -238,8 +240,12 @@ class Prefetcher:
         err.__cause__ = exc  # original traceback survives the hop
         return err
 
+    def _stage_span(self, k, r):
+        with span("fedmeta.round.stage", round=r):
+            return self._produce(k)
+
     def _produce_with_retry(self, k, r):
-        out = call_with_retry(lambda: self._produce(k),
+        out = call_with_retry(lambda: self._stage_span(k, r),
                               max_retries=self._max_retries,
                               backoff=self._retry_backoff,
                               stop=self._stop)
@@ -556,20 +562,25 @@ class AsyncRoundEngine:
         blocks = plan_blocks(rounds, eval_every if evaluate else 0, fuse,
                              start=start_round)
         pending: list = []
+        first = start_round + 1   # the current block's first round
 
         def flush():
             # the only host-device sync in the loop: float() on the
             # pending rounds' still-on-device metric arrays
-            for n, metrics, comm_rounds, eval_fields in pending:
-                rec = {"round": n,
-                       **{k: float(v) for k, v in metrics.items()},
-                       **self.comm.summary_at(comm_rounds)}
-                if eval_fields:
-                    rec.update(eval_fields)
-                self.history.append(rec)
-                if log:
-                    log(rec)
-            pending.clear()
+            if not pending:
+                return
+            with span("fedmeta.round.flush", round=first,
+                      rounds=len(pending)):
+                for n, metrics, comm_rounds, eval_fields in pending:
+                    rec = {"round": n,
+                           **{k: float(v) for k, v in metrics.items()},
+                           **self.comm.summary_at(comm_rounds)}
+                    if eval_fields:
+                        rec.update(eval_fields)
+                    self.history.append(rec)
+                    if log:
+                        log(rec)
+                pending.clear()
 
         prefetch = None
         if self.prefetch_depth > 0:
@@ -580,37 +591,48 @@ class AsyncRoundEngine:
         last_ckpt = start_round
         try:
             for bk in blocks:
-                staged = prefetch.get() if prefetch else self.stage(bk)
-                if bk == 1:
-                    state, metrics = self.step(state, staged)
-                    per_round = [metrics]
-                else:
-                    state, stacked = self.fused_step(state, staged)
-                    per_round = [
-                        jax.tree.map(lambda x, i=i: x[i], stacked)
-                        for i in range(bk)]
-                for metrics in per_round:
-                    r += 1
-                    self.comm.tick()
-                    eval_fields = None
-                    if evaluate and eval_every and \
-                            (r % eval_every == 0 or r == rounds):
-                        eval_fields = evaluate(state)
-                    pending.append((r, metrics, self.comm.rounds,
-                                    eval_fields))
-                    # eval rounds already synced the device to read φ,
-                    # so draining there is free
-                    if eval_fields is not None or (
-                            self.flush_every and
-                            r % self.flush_every == 0):
+                first = r + 1
+                with span("fedmeta.round", round=first, k=bk):
+                    if prefetch:
+                        with span("fedmeta.round.prefetch_wait", round=first):
+                            staged = prefetch.get()
+                    else:
+                        with span("fedmeta.round.stage", round=first):
+                            staged = self.stage(bk)
+                    with span("fedmeta.round.dispatch", round=first,
+                              compiles=compiles()):
+                        if bk == 1:
+                            state, metrics = self.step(state, staged)
+                            per_round = [metrics]
+                        else:
+                            state, stacked = self.fused_step(state, staged)
+                            per_round = [
+                                jax.tree.map(lambda x, i=i: x[i], stacked)
+                                for i in range(bk)]
+                    for metrics in per_round:
+                        r += 1
+                        self.comm.tick()
+                        eval_fields = None
+                        if evaluate and eval_every and \
+                                (r % eval_every == 0 or r == rounds):
+                            with span("fedmeta.round.eval", round=first):
+                                eval_fields = evaluate(state)
+                        pending.append((r, metrics, self.comm.rounds,
+                                        eval_fields))
+                        # eval rounds already synced the device to read φ,
+                        # so draining there is free
+                        if eval_fields is not None or (
+                                self.flush_every and
+                                r % self.flush_every == 0):
+                            flush()
+                    if (self.checkpoint is not None and self.checkpoint_every
+                            and r - last_ckpt >= self.checkpoint_every):
+                        # flush first: the payload captures history up to
+                        # and including round r, never a pending tail
                         flush()
-                if (self.checkpoint is not None and self.checkpoint_every
-                        and r - last_ckpt >= self.checkpoint_every):
-                    # flush first: the payload captures history up to
-                    # and including round r, never a pending tail
-                    flush()
-                    self.checkpoint(state, r)
-                    last_ckpt = r
+                        with span("fedmeta.round.checkpoint", round=first):
+                            self.checkpoint(state, r)
+                        last_ckpt = r
             return state
         finally:
             if prefetch is not None:

@@ -31,6 +31,7 @@ from repro.federated.population import (CircuitBreaker, UnreliabilityConfig,
 from repro.kernels.meta_update import ops as mu_ops
 from repro.optim import Optimizer
 from repro.utils.flat import plane_for
+from repro.utils.trace import span
 
 
 def _rng_state_payload(state):
@@ -121,6 +122,14 @@ def evaluate_global(eval_fn, theta, clients, *, support_frac, support_size,
         support_size, query_size, rng)
     acc, loss = _count_weighted(accs, losses, counts)
     return acc, accs, loss
+
+
+def _put(dp, tree, round_):
+    """Stage every host array of ``tree`` with ``dp`` (``None``s kept),
+    under one span whose stat ``bytes`` is their total size."""
+    nbytes = sum(x.nbytes for x in jax.tree.leaves(tree))
+    with span("fedmeta.round.put", round=round_, bytes=nbytes):
+        return jax.tree.map(dp, tree)
 
 
 @dataclasses.dataclass
@@ -397,40 +406,43 @@ class FederatedTrainer:
         trailing ``None``s trimmed, so every off-knob configuration
         stages byte-for-byte the argument tuple it staged before the
         knob existed (the PR 4–7 shipping invariant)."""
-        if k > 1:   # fused-K: one stacked (k, m, ...) staged buffer
-            tb = stack_task_batches(stream.take(k))
-            return ((dp(tb.support_x), dp(tb.support_y)),
-                    (dp(tb.query_x), dp(tb.query_y)),
-                    dp(tb.weight) if self.weighted else None)
-        tb = stream.next()
-        args = ((dp(tb.support_x), dp(tb.support_y)),
-                (dp(tb.query_x), dp(tb.query_y)),
-                dp(tb.weight) if self.weighted else None)
-        sel = None
+        with span("fedmeta.round.sample", round=round_):
+            if k > 1:   # fused-K: one stacked (k, m, ...) staged buffer
+                tb = stack_task_batches(stream.take(k))
+                tail = [None] * 4
+            else:
+                tb = stream.next()
+                tail = self._round_tail(tb, round_)
+            args = ((tb.support_x, tb.support_y), (tb.query_x, tb.query_y),
+                    tb.weight if self.weighted else None)
+        # the dp key is made on the device already; the rest is staged
+        args, tail[:3] = _put(dp, (args, tail[:3]), round_)
+        while tail and tail[-1] is None:
+            tail.pop()
+        return args + tuple(tail)
+
+    def _round_tail(self, tb, round_) -> list:
+        """The host draws of one round's optional inputs,
+        ``[stale_sel, fault, ef_idx, dp_key]`` (``None`` where off)."""
+        sel = fault = ef_idx = dp_key = None
         if self.staleness is not None:
             # (straggler_idx, fresh_idx[, delays]) — delays only
             # with jitter on, so the off-path stays bit-identical
-            sel = tuple(dp(s) for s in self.staleness.pick(
-                self.clients_per_round, self._stale_rng))
-        fault = None
+            sel = self.staleness.pick(self.clients_per_round,
+                                      self._stale_rng)
         if self.faults is not None:
-            fault = tuple(dp(f) for f in self.faults.pick(
-                self.clients_per_round, self._fault_rng))
-        ef_idx = None
+            fault = tuple(self.faults.pick(self.clients_per_round,
+                                           self._fault_rng))
         if self.compression is not None and \
                 self.compression.error_feedback:
             # this round's picks = the residual-plane rows the step
             # gathers/scatters (recorded by the sampler; no extra draw)
-            ef_idx = dp(np.asarray(tb.client_idx, np.int32))
-        dp_key = None
+            ef_idx = np.asarray(tb.client_idx, np.int32)
         if self.dp is not None and self.dp.noise_multiplier > 0:
             # pure function of the round index: prefetch/resume-safe
             # with nothing checkpointed
             dp_key = self.dp.round_key(round_)
-        tail = [sel, fault, ef_idx, dp_key]
-        while tail and tail[-1] is None:
-            tail.pop()
-        return args + tuple(tail)
+        return [sel, fault, ef_idx, dp_key]
 
     # ---- population plane (DESIGN.md §15) ---------------------------
     def _peek_picks(self):
@@ -451,6 +463,15 @@ class FederatedTrainer:
         (through the worker pool when configured), and build the
         zero-weight-padded batch the `masked_mean` step renormalizes.
         Runs on the prefetch thread (in round order) when pipelined."""
+        with span("fedmeta.round.sample", round=round_):
+            args, fault = self._sample_population(round_)
+        args, fault = _put(dp, (args, fault), round_)
+        if self.faults is not None:
+            args += (None, fault)   # stale_sel placeholder (positional)
+        return args
+
+    def _sample_population(self, round_):
+        """-> (host batch arrays, fault draw or None) of one round."""
         clients = self.train_clients
         m = self.clients_per_round
         rng = self._rng
@@ -486,13 +507,12 @@ class FederatedTrainer:
         # download: φ went to every candidate; upload: only arrivals
         self.comm.record_round(len(cand), len(idxs), len(quar))
         # weights always staged: the zero rows ARE the arrival mask
-        args = ((dp(tb.support_x), dp(tb.support_y)),
-                (dp(tb.query_x), dp(tb.query_y)), dp(tb.weight))
+        args = ((tb.support_x, tb.support_y), (tb.query_x, tb.query_y),
+                tb.weight)
+        fault = None
         if self.faults is not None:
-            args += (None,)   # stale_sel placeholder (positional call)
-            fault = self.faults.pick(m, self._fault_rng)
-            args += (tuple(dp(f) for f in fault),)
-        return args
+            fault = tuple(self.faults.pick(m, self._fault_rng))
+        return args, fault
 
     # ---- crash-safe checkpointing (DESIGN.md §14) -------------------
     def _capture_rngs(self):
@@ -587,6 +607,12 @@ class FederatedTrainer:
         appended EVERY round — convergence curves at full resolution,
         not subsampled to eval_every; eval fields only when evaluated.
         ``start_round`` continues a resumed run (see ``resume``)."""
+        with span("fedmeta.run", start_round=start_round, rounds=rounds):
+            return self._run(state, rounds, eval_every, eval_clients, log,
+                             start_round)
+
+    def _run(self, state, rounds, eval_every, eval_clients, log,
+             start_round):
         stream = TaskStream(self.train_clients, self.clients_per_round,
                             self.support_frac, self.support_size,
                             self.query_size, self._rng)

@@ -31,6 +31,7 @@ from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import PRODUCTION_DATA, make_device_mesh
 from repro.launch.steps import input_specs, make_train_step, train_batch_layout
 from repro.sharding.rules import param_pspecs, state_pspecs
+from repro.utils import trace  # noqa: F401  registers the compile counter
 
 
 def per_chip_shape(shape: InputShape, n_data: int) -> InputShape:
