@@ -59,6 +59,15 @@ def smollm_plane():
     return plane_for(shapes)
 
 
+@pytest.fixture(scope="module")
+def femnist_plane():
+    """The benchmark's femnist CNN plane (2048 hidden units, 6,603,776
+    padded f32 parameters; shapes only)."""
+    from repro.models.paper import femnist_cnn
+    model = femnist_cnn(num_classes=62, image_size=28, hidden=2048)
+    return plane_for(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+
+
 def _compile(fn, sharding, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
@@ -66,22 +75,26 @@ def _compile(fn, sharding, *shapes):
     return text
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32", "bf16"])
-def test_inner_update_plane(one_chip, smollm_plane, dtype):
-    """The serving adapt batch (2 clients) on the smollm-360m plane."""
-    n = smollm_plane.n_padded
+@pytest.mark.parametrize("plane,clients,dtype", [
+    ("smollm", 2, jnp.float32), ("smollm", 2, jnp.bfloat16),
+    ("femnist", 32, jnp.float32)], ids=["f32", "bf16", "femnist32-f32"])
+def test_inner_update_plane(request, one_chip, plane, clients, dtype):
+    """The serving adapt batch (2 clients) on the smollm-360m plane, and
+    the benchmark's 32-writer femnist round (N/1024 = 6,449, prime)."""
+    n = request.getfixturevalue(f"{plane}_plane").n_padded
     _compile(lambda t, g: inner_update_plane(t, 0.05, g), one_chip,
-             ((2, n), dtype), ((2, n), dtype))
+             ((clients, n), dtype), ((clients, n), dtype))
 
 
-def test_weighted_aggregate_flat(one_chip):
-    """A 1000-client round of the femnist plan's CNN."""
+def test_weighted_aggregate_flat(one_chip, femnist_plane):
+    """A 1000-client round of the femnist plan's CNN, and the
+    benchmark's 32-writer round of the 2048-unit CNN."""
     from repro.federated.experiment import DATASETS
     model = DATASETS["femnist"]["model"]()
     n = plane_for(jax.eval_shape(model.init, jax.random.PRNGKey(0))).n_padded
-    _compile(weighted_aggregate_flat, one_chip,
-             ((1000, n), jnp.float32), ((1000,), jnp.float32))
+    for clients, width in [(1000, n), (32, femnist_plane.n_padded)]:
+        _compile(weighted_aggregate_flat, one_chip,
+                 ((clients, width), jnp.float32), ((clients,), jnp.float32))
 
 
 @pytest.mark.parametrize("state_dtype", [jnp.float32, jnp.bfloat16],
