@@ -38,6 +38,14 @@ class ModelConfig:
     q_lora_rank: int = 0
     rope_head_dim: int = 64
 
+    # --- YaRN rope scaling (deepseek-v2); yarn_factor 0 = plain rope ---
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+
     # --- MoE ---
     num_experts: int = 0
     num_experts_per_tok: int = 0
@@ -46,6 +54,17 @@ class ModelConfig:
     first_k_dense: int = 0          # leading dense layers (deepseek-v2: 1)
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    dense_d_ff: int = 0             # FFN width of non-MoE layers (0: d_ff)
+    # gating: "topk_softmax" (Mixtral: top-k of the logits, softmax over
+    # the k) or "softmax" (DeepSeek: softmax over every expert in f32,
+    # then the top-k probabilities, renormalized iff norm_topk_prob)
+    router_scoring: str = "topk_softmax"
+    norm_topk_prob: bool = False
+    routed_scaling_factor: float = 1.0
+    # routed experts this chip holds, from first_expert (0: all of them);
+    # the router stays num_experts wide
+    experts_held: int = 0
+    first_expert: int = 0
 
     # --- SSM (mamba2 / jamba) ---
     ssm_state: int = 0
@@ -85,11 +104,33 @@ class ModelConfig:
         assert self.num_layers % len(self.layer_pattern) == 0, (
             f"{self.name}: num_layers {self.num_layers} must be a multiple of "
             f"the layer pattern period {len(self.layer_pattern)}")
+        assert self.router_scoring in ("topk_softmax", "softmax"), (
+            self.router_scoring)
+        if self.experts_held:
+            assert self.router_scoring == "softmax", (
+                f"{self.name}: a layer told which experts it holds routes "
+                f"by softmax gating")
+            assert self.moe_impl != "ep", (
+                f"{self.name}: a layer told which experts it holds runs "
+                f"without the expert all-to-all")
+            assert 0 <= self.first_expert and (
+                self.first_expert + self.experts_held <= self.num_experts), (
+                self.first_expert, self.experts_held, self.num_experts)
 
     @property
     def d_inner(self) -> int:
         """SSM inner width."""
         return self.ssm_expand * self.d_model
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts this chip computes."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def mlp_d_ff(self) -> int:
+        """FFN width of the layers that are not MoE."""
+        return self.dense_d_ff or self.d_ff
 
     def is_moe_layer(self, i: int) -> bool:
         if self.num_experts == 0 or i < self.first_k_dense:
@@ -103,6 +144,7 @@ _ARCH_MODULES = {
     "granite-3-2b": "repro.configs.granite_3_2b",
     "seamless-m4t-medium": "repro.configs.seamless_m4t_medium",
     "deepseek-v2-236b": "repro.configs.deepseek_v2_236b",
+    "deepseek-v2-lite": "repro.configs.deepseek_v2_lite",
     "qwen2-vl-7b": "repro.configs.qwen2_vl_7b",
     "mamba2-370m": "repro.configs.mamba2_370m",
     "qwen2.5-3b": "repro.configs.qwen2_5_3b",
@@ -141,10 +183,13 @@ def reduced_config(cfg: ModelConfig, *, seq_friendly: bool = True) -> ModelConfi
         num_kv_heads=n_kv,
         head_dim=head_dim,
         d_ff=min(cfg.d_ff, 512) if cfg.d_ff else 0,
+        dense_d_ff=min(cfg.dense_d_ff, 512),
         vocab_size=min(cfg.vocab_size, 512),
         num_experts=min(cfg.num_experts, 4),
         num_experts_per_tok=min(cfg.num_experts_per_tok, 2),
         num_shared_experts=min(cfg.num_shared_experts, 1),
+        experts_held=min(cfg.experts_held, 4),
+        first_expert=0,
         first_k_dense=min(cfg.first_k_dense, 1 if layers > 1 else 0),
         kv_lora_rank=min(cfg.kv_lora_rank, 32),
         q_lora_rank=min(cfg.q_lora_rank, 32),

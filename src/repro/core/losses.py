@@ -54,27 +54,28 @@ def lm_loss(apply_fn):
 
     Batches are either a (B, L) token array or a dict with "tokens"
     (+ "embeds" for modality archs — consumed by apply_fn).
-    apply_fn(params, batch) -> (logits (B, L', V), aux) — aux (e.g. MoE
-    load-balance loss) is added to the objective so the router trains in
-    both FedMeta loops. L' may include a modality prefix; loss aligns to
-    the last L text positions."""
+    apply_fn(params, batch) -> (logits (B, L', V), aux[, stats]) — aux
+    (e.g. MoE load-balance loss) is added to the objective so the router
+    trains in both FedMeta loops; stats (the MoE routing counters), where
+    given, join the eval metrics. L' may include a modality prefix; loss
+    aligns to the last L text positions."""
 
     def _tokens(batch):
         return batch["tokens"] if isinstance(batch, dict) else batch
 
     def loss_fn(params, batch):
         tokens = _tokens(batch)
-        logits, aux = apply_fn(params, batch)
+        logits, aux = apply_fn(params, batch)[:2]
         logits = logits[:, -tokens.shape[1]:]
         return softmax_xent(logits[:, :-1], tokens[:, 1:]) + aux
 
     def eval_fn(params, batch):
         tokens = _tokens(batch)
-        logits, aux = apply_fn(params, batch)
+        logits, aux, *stats = apply_fn(params, batch)
         logits = logits[:, -tokens.shape[1]:]
         loss = softmax_xent(logits[:, :-1], tokens[:, 1:])
         return loss + aux, {"accuracy": accuracy(logits[:, :-1], tokens[:, 1:]),
-                            "nll": loss}
+                            "nll": loss, **(stats[0] if stats else {})}
 
     return loss_fn, eval_fn
 

@@ -77,12 +77,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "window", "q_offset", "block_q", "block_k",
-                     "interpret"))
+                     "interpret", "scale"))
 def flash_attention_bhld(q, k, v, *, causal: bool = True, window=None,
                          q_offset: int = 0, block_q: int = 128,
-                         block_k: int = 128, interpret: bool = False):
+                         block_k: int = 128, interpret: bool = False,
+                         scale: float | None = None):
     """q: (B, H, Lq, hd); k: (B, Kv, Lk, hd); v: (B, Kv, Lk, hd_v).
-    Returns (B, H, Lq, hd_v) — hd_v may differ from hd (MLA)."""
+    Returns (B, H, Lq, hd_v) — hd_v may differ from hd (MLA). The
+    softmax scale defaults to 1/sqrt(hd)."""
     B, H, Lq, hd = q.shape
     _, Kv, Lk, _ = k.shape
     hd_v = v.shape[-1]
@@ -92,7 +94,8 @@ def flash_attention_bhld(q, k, v, *, causal: bool = True, window=None,
     bk = min(block_k, Lk)
     assert Lq % bq == 0 and Lk % bk == 0, (Lq, bq, Lk, bk)
     nq, nk = Lq // bq, Lk // bk
-    scale = 1.0 / (hd ** 0.5)
+    if scale is None:
+        scale = 1.0 / (hd ** 0.5)
 
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, window=window,

@@ -52,18 +52,34 @@ def make_apply_fn(cfg: ModelConfig, *, remat: bool = True,
     differentiate. The flash-attention and SSD kernels have no backward,
     so attention and the SSD scan are pinned to their XLA paths here,
     whatever the platform (serving's prefill and decode keep the
-    kernels)."""
+    kernels). Where the MoE layers count their routing (DeepSeek
+    gating), apply returns (logits, aux, stats) and the LM loss reports
+    the counters."""
 
     def apply_fn(params, batch):
         with attn_ops.use_impl("xla"), ssd_ops.use_impl("xla"):
             if isinstance(batch, dict):
-                return lm_apply(params, cfg, batch["tokens"],
-                                modality_embeds=batch.get("embeds"),
-                                remat=remat, unroll_layers=unroll_layers)
-            return lm_apply(params, cfg, batch, remat=remat,
-                            unroll_layers=unroll_layers)
+                tokens, embeds = batch["tokens"], batch.get("embeds")
+            else:
+                tokens, embeds = batch, None
+            logits, aux, stats = lm_apply(
+                params, cfg, tokens, modality_embeds=embeds, remat=remat,
+                unroll_layers=unroll_layers, return_stats=True)
+            return (logits, aux, stats) if stats else (logits, aux)
 
     return apply_fn
+
+
+def reduce_metrics(mets, axis: int = 0):
+    """Per-client metrics -> the round's: means, except the MoE routing
+    counters, which are summed (`moe_load_max`: the largest)."""
+    def one(k, x):
+        if k == "moe_load_max":
+            return jnp.max(x, axis=axis)
+        if k.startswith("moe_"):
+            return jnp.sum(x, axis=axis)
+        return jnp.mean(x, axis=axis)
+    return {k: one(k, x) for k, x in mets.items()}
 
 
 # ------------------------------------------------------------- train step
@@ -126,12 +142,11 @@ def make_train_step(cfg: ModelConfig, *, algo_name: str = "fomaml",
                     mets_list.append(met)
                 mets = jax.tree.map(lambda *xs: jnp.stack(xs), *mets_list)
             meta_g = jax.tree.map(lambda x: x / C, meta_g)
-            mets = jax.tree.map(jnp.mean, mets)
-            return meta_g, mets
+            return meta_g, reduce_metrics(mets)
 
         meta_g, mets = jax.vmap(per_group)(batch["support"], batch["query"])
         meta_g = jax.tree.map(lambda x: jnp.mean(x, axis=0), meta_g)
-        mets = jax.tree.map(lambda x: jnp.mean(x, axis=0), mets)
+        mets = reduce_metrics(mets)
         phi, opt = optimizer.update(state["phi"], meta_g, state["opt"])
         return {"phi": phi, "opt": opt}, mets
 

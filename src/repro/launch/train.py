@@ -30,8 +30,24 @@ from repro.data.lm_tasks import make_lm_task_batch
 from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import PRODUCTION_DATA, make_device_mesh
 from repro.launch.steps import input_specs, make_train_step, train_batch_layout
+from repro.models.moe import STATS as ROUTING
 from repro.sharding.rules import param_pspecs, state_pspecs
-from repro.utils import trace  # noqa: F401  registers the compile counter
+from repro.utils import trace  # registers the compile counter
+
+ROUTE_SPAN = "fedmeta.moe.route"
+
+
+def record_routing(metrics) -> dict | None:
+    """A round's MoE routing counters (`moe_pairs_held`, `moe_load_max`,
+    `moe_dropped`; DESIGN.md §19) read from the step's metrics and left
+    in the trace as the stats of a `fedmeta.moe.route` span; None where
+    the model counts none."""
+    if ROUTING[0] not in metrics:
+        return None
+    counts = {k: int(metrics[k]) for k in ROUTING}
+    with trace.span(ROUTE_SPAN, **counts):
+        pass
+    return counts
 
 
 def per_chip_shape(shape: InputShape, n_data: int) -> InputShape:
@@ -141,11 +157,13 @@ def main():
         t0 = time.perf_counter()
         state, metrics = step(state, batch)
         jax.block_until_ready(metrics)
+        routing = record_routing(metrics)
         if (it + 1) % args.log_every == 0:
             print(f"step {it+1:4d}  loss="
                   f"{float(metrics['query_loss']):.4f}  acc="
                   f"{float(metrics['accuracy']):.4f}  "
-                  f"({time.perf_counter()-t0:.2f}s)", flush=True)
+                  f"({time.perf_counter()-t0:.2f}s)"
+                  + (f"  routing={routing}" if routing else ""), flush=True)
     if args.ckpt:
         host_state = jax.device_get(state)
         path = save_server_state(args.ckpt, args.steps, host_state)

@@ -17,7 +17,8 @@ import numpy as np
 
 from repro.kernels.attention import ops as attn_ops
 from repro.models.layers import (Rng, apply_mrope, apply_rope, dense_init,
-                                 rmsnorm, rmsnorm_init, text_mrope_positions)
+                                 rmsnorm, rmsnorm_init, text_mrope_positions,
+                                 yarn_frequencies, yarn_mscale)
 
 
 # ================================================================= GQA
@@ -142,11 +143,36 @@ def mla_init(rng: Rng, cfg, dtype):
     return p
 
 
+def _mla_rope(cfg, x, positions):
+    """Rotary positions on MLA's decoupled rope part; under YaRN its
+    frequencies, and cos/sin times mscale(factor, mscale) /
+    mscale(factor, mscale_all_dim)."""
+    f = cfg.yarn_factor
+    if not f:
+        return apply_rope(x, positions, cfg.rope_theta)
+    inv = yarn_frequencies(x.shape[-1], cfg.rope_theta, f,
+                           cfg.yarn_original_max_pos, cfg.yarn_beta_fast,
+                           cfg.yarn_beta_slow)
+    m = yarn_mscale(f, cfg.yarn_mscale) / yarn_mscale(
+        f, cfg.yarn_mscale_all_dim)
+    return apply_rope(x, positions, cfg.rope_theta, inv_freq=inv, mscale=m)
+
+
+def mla_softmax_scale(cfg) -> float:
+    """(nope + rope_d)^-1/2, times mscale(factor, mscale_all_dim)^2 under
+    YaRN (DeepSeek-V2's attention)."""
+    scale = 1.0 / np.sqrt(cfg.head_dim + cfg.rope_head_dim)
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return float(scale)
+
+
 def _mla_q(params, cfg, x):
     B, L, _ = x.shape
     H, nope, rope_d = cfg.num_heads, cfg.head_dim, cfg.rope_head_dim
     if cfg.q_lora_rank > 0:
-        q = rmsnorm(params["q_norm"], x @ params["w_dq"]) @ params["w_uq"]
+        q = rmsnorm(params["q_norm"], x @ params["w_dq"],
+                    cfg.norm_eps) @ params["w_uq"]
     else:
         q = x @ params["wq"]
     q = q.reshape(B, L, H, nope + rope_d)
@@ -154,10 +180,10 @@ def _mla_q(params, cfg, x):
 
 
 def _mla_latents(params, cfg, x, positions):
-    c = rmsnorm(params["kv_norm"], x @ params["w_dkv"])      # (B, L, R)
+    c = rmsnorm(params["kv_norm"], x @ params["w_dkv"],
+                cfg.norm_eps)                                # (B, L, R)
     kpe = x @ params["w_kpe"]                                # (B, L, rope_d)
-    kpe = apply_rope(kpe[:, :, None, :], positions,
-                     cfg.rope_theta)[:, :, 0, :]
+    kpe = _mla_rope(cfg, kpe[:, :, None, :], positions)[:, :, 0, :]
     return c, kpe
 
 
@@ -167,15 +193,15 @@ def mla_forward(params, cfg, x, positions, *, causal: bool = True,
     B, L, _ = x.shape
     H, nope, rope_d = cfg.num_heads, cfg.head_dim, cfg.rope_head_dim
     q_nope, q_pe = _mla_q(params, cfg, x)
-    q_pe = apply_rope(q_pe, positions, cfg.rope_theta)
+    q_pe = _mla_rope(cfg, q_pe, positions)
     c, kpe = _mla_latents(params, cfg, x, positions)
     k_nope = (c @ params["w_uk"]).reshape(B, L, H, nope)
     v = (c @ params["w_uv"]).reshape(B, L, H, nope)
     q = jnp.concatenate([q_nope, q_pe], axis=-1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(kpe[:, :, None, :],
                                                   (B, L, H, rope_d))], axis=-1)
-    # scale uses the full qk dim (nope + rope_d)
-    o = attn_ops.flash_attention(q, k, v, causal=causal)
+    o = attn_ops.flash_attention(q, k, v, causal=causal,
+                                 scale=mla_softmax_scale(cfg))
     y = o.reshape(B, L, H * nope) @ params["wo"]
     return (y, (c, kpe)) if return_latents else y
 
@@ -199,7 +225,7 @@ def mla_decode(params, cfg, x, cache, length):
     C = cache["c"].shape[1]
     pos = jnp.full((B, 1), length, jnp.int32)
     q_nope, q_pe = _mla_q(params, cfg, x)                   # (B,1,H,·)
-    q_pe = apply_rope(q_pe, pos, cfg.rope_theta)
+    q_pe = _mla_rope(cfg, q_pe, pos)
     c_new, kpe_new = _mla_latents(params, cfg, x, pos)
     slot = (length % C).astype(jnp.int32)
     c = jax.lax.dynamic_update_slice(cache["c"], c_new.astype(cache["c"].dtype),
@@ -215,7 +241,7 @@ def mla_decode(params, cfg, x, cache, length):
     s = jnp.einsum("bhr,bjr->bhj", q_lat, c.astype(jnp.float32))
     s = s + jnp.einsum("bhd,bjd->bhj", q_pe[:, 0].astype(jnp.float32),
                        kpe.astype(jnp.float32))
-    s = s / np.sqrt(nope + rope_d)
+    s = s * mla_softmax_scale(cfg)
     mask = jnp.arange(C)[None, None, :] < valid
     s = jnp.where(mask, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)

@@ -46,15 +46,18 @@ def block_init(rng: Rng, cfg, spec, dtype, *, cross: bool = False):
         if ffn == "moe":
             p["ffn"] = moe_mod.moe_init(rng, cfg, dtype)
         else:
-            p["ffn"] = mlp_init(rng, cfg.d_model, cfg.d_ff, cfg.mlp_act, dtype)
+            p["ffn"] = mlp_init(rng, cfg.d_model, cfg.mlp_d_ff, cfg.mlp_act,
+                                dtype)
     return p
 
 
 def block_forward(params, cfg, spec, x, positions, *, causal: bool = True,
                   enc_out=None):
-    """Full-sequence forward. Returns (y, aux_loss)."""
+    """Full-sequence forward. Returns (y, aux_loss, stats): stats are
+    the MoE layer's routing counters ({} where it counts none)."""
     kind, ffn = spec
     aux = jnp.zeros((), jnp.float32)
+    stats = {}
     h = rmsnorm(params["norm1"], x, cfg.norm_eps)
     if kind == "attn":
         if cfg.attention == "mla":
@@ -72,23 +75,27 @@ def block_forward(params, cfg, spec, x, positions, *, causal: bool = True,
     if ffn != "none":
         h = rmsnorm(params["norm2"], x, cfg.norm_eps)
         if ffn == "moe":
-            y, aux = _moe(params["ffn"], cfg, h)
+            y, aux, stats = _moe(params["ffn"], cfg, h)
         else:
             y = mlp_apply(params["ffn"], h, cfg.mlp_act)
         x = x + y
-    return x, aux
+    return x, aux, stats
 
 
 def _moe(params, cfg, h):
-    """Dispatch to the configured MoE implementation (perf lever)."""
+    """Dispatch to the configured MoE implementation (perf lever).
+    -> (y, aux_loss, routing counters)."""
     if cfg.moe_impl == "ep":
         from repro.sharding.context import get_mesh
         mesh = get_mesh()
         if mesh is not None:
             from repro.sharding.ep_moe import ep_moe_apply
-            return ep_moe_apply(params, cfg, h, mesh), jnp.zeros((),
-                                                                 jnp.float32)
-    return moe_mod.moe_apply(params, cfg, h)
+            return (ep_moe_apply(params, cfg, h, mesh),
+                    jnp.zeros((), jnp.float32), {})
+    if cfg.router_scoring == "softmax":
+        return moe_mod.held_moe_apply(params, cfg, h)
+    y, aux = moe_mod.moe_apply(params, cfg, h)
+    return y, aux, {}
 
 
 def _ring_place(full, capacity: int):
@@ -131,7 +138,7 @@ def block_prefill(params, cfg, spec, x, positions, capacity: int, *,
     if ffn != "none":
         h = rmsnorm(params["norm2"], x, cfg.norm_eps)
         if ffn == "moe":
-            y, aux = _moe(params["ffn"], cfg, h)
+            y, aux, _ = _moe(params["ffn"], cfg, h)
         else:
             y = mlp_apply(params["ffn"], h, cfg.mlp_act)
         x = x + y
@@ -167,7 +174,7 @@ def block_decode(params, cfg, spec, x, cache, length, *, enc_out=None):
     if ffn != "none":
         h = rmsnorm(params["norm2"], x, cfg.norm_eps)
         if ffn == "moe":
-            y, _ = _moe(params["ffn"], cfg, h)
+            y, _, _ = _moe(params["ffn"], cfg, h)
         else:
             y = mlp_apply(params["ffn"], h, cfg.mlp_act)
         x = x + y

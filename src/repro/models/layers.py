@@ -3,6 +3,8 @@ embeddings (incl. M-RoPE). Functional style: params are nested dicts.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -80,13 +82,50 @@ def rope_frequencies(head_dim: int, theta: float):
     return 1.0 / (theta ** (np.arange(0, half, dtype=np.float32) / half))
 
 
-def apply_rope(x, positions, theta: float):
-    """Standard RoPE. x: (..., L, H, hd); positions: (..., L) int32."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor 0.1·mscale·ln(factor) + 1 (1 unscaled)."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(dim: int, theta: float, factor: float,
+                     original_max_pos: int, beta_fast: float,
+                     beta_slow: float):
+    """YaRN inverse frequencies for half of `dim`, as DeepSeek-V2's
+    rotary builds them: the unscaled frequencies where a dimension turns
+    more than `beta_fast` times over the original context, those divided
+    by `factor` where it turns fewer than `beta_slow` times, and a linear
+    ramp between the two."""
+    extra = rope_frequencies(dim, theta)
+    inter = extra / np.float32(factor)
+
+    def correction_dim(rotations):
+        return (dim * math.log(original_max_pos / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    keep = 1.0 - ramp            # 1: the unscaled frequency
+    return (inter * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def apply_rope(x, positions, theta: float, inv_freq=None, mscale=1.0):
+    """Standard RoPE. x: (..., L, H, hd); positions: (..., L) int32.
+    `inv_freq` (hd/2,) replaces the plain frequencies of `theta` (YaRN);
+    `mscale` multiplies cos and sin."""
     hd = x.shape[-1]
-    inv = jnp.asarray(rope_frequencies(hd, theta))          # (hd/2,)
+    inv = jnp.asarray(rope_frequencies(hd, theta) if inv_freq is None
+                      else inv_freq)                        # (hd/2,)
     ang = positions[..., None].astype(jnp.float32) * inv    # (..., L, hd/2)
     cos = jnp.cos(ang)[..., None, :]                        # (..., L, 1, hd/2)
     sin = jnp.sin(ang)[..., None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.models.blocks import (block_decode, block_forward, block_init,
                                  block_init_cache, block_prefill, layer_spec)
+from repro.models.moe import merge_stats, zero_stats
 from repro.models.layers import (Rng, dense_init, embed_init, rmsnorm,
                                  rmsnorm_init, text_mrope_positions)
 
@@ -109,8 +110,8 @@ def _run_encoder(params, cfg, frames):
     enc_spec = ("attn", "mlp")
 
     def body(carry, rep_params):
-        h, _ = block_forward(rep_params, cfg, enc_spec, carry, pos,
-                             causal=False)
+        h, _, _ = block_forward(rep_params, cfg, enc_spec, carry, pos,
+                                causal=False)
         return h, None
 
     x, _ = jax.lax.scan(body, x, params["encoder"]["stack"])
@@ -143,13 +144,17 @@ def _logits(params, cfg, x):
 
 def lm_apply(params, cfg, tokens, *, modality_embeds=None, remat: bool = True,
              collect_cache: bool = False, cache_capacity: int | None = None,
-             logits_mode: str = "all", unroll_layers: bool = False):
+             logits_mode: str = "all", unroll_layers: bool = False,
+             return_stats: bool = False):
     """Training / prefill forward.
 
     tokens: (B, L_text) int32. modality_embeds: (B, n_mod, d_model) for
     vlm/audio archs (the stub frontend's output). Returns
-    (logits, aux_loss[, cache]). For vlm, logits cover the full
-    [prefix|text] sequence; the caller slices text positions for loss.
+    (logits, aux_loss[, cache]), or with return_stats (no cache)
+    (logits, aux_loss, stats): the MoE layers' routing counters
+    (`models/moe.STATS`; {} for configs that count none). For vlm,
+    logits cover the full [prefix|text] sequence; the caller slices
+    text positions for loss.
     """
     B, L_text = tokens.shape
     lead, period, n_reps = layer_groups(cfg)
@@ -166,6 +171,7 @@ def lm_apply(params, cfg, tokens, *, modality_embeds=None, remat: bool = True,
             [modality_embeds.astype(x.dtype) @ params["mod_proj"], x], axis=1)
     positions = _positions(cfg, n_mod, L_text, B)
     aux = jnp.zeros((), jnp.float32)
+    stats = zero_stats(cfg)
     L_total = n_mod + L_text
     capacity = cache_capacity or L_total
 
@@ -176,8 +182,9 @@ def lm_apply(params, cfg, tokens, *, modality_embeds=None, remat: bool = True,
                 params[f"lead_{i}"], cfg, spec, x, positions, capacity,
                 enc_out=enc_out)
         else:
-            x, a = block_forward(params[f"lead_{i}"], cfg, spec, x, positions,
-                                 enc_out=enc_out)
+            x, a, st = block_forward(params[f"lead_{i}"], cfg, spec, x,
+                                     positions, enc_out=enc_out)
+            stats = merge_stats(stats, st)
         aux = aux + a
 
     if collect_cache:
@@ -204,13 +211,14 @@ def lm_apply(params, cfg, tokens, *, modality_embeds=None, remat: bool = True,
         caches["stack"] = stack_caches
     else:
         def body(carry, rep_params):
-            h, acc = carry
+            h, acc, st = carry
             for j, spec in enumerate(period):
-                h, a = block_forward(rep_params[f"pos{j}"], cfg, spec, h,
-                                     positions, enc_out=enc_out)
+                h, a, s = block_forward(rep_params[f"pos{j}"], cfg, spec, h,
+                                        positions, enc_out=enc_out)
                 acc = acc + a
+                st = merge_stats(st, s)
             h = _maybe_shard_seq(cfg, h)
-            return (h, acc), None
+            return (h, acc, st), None
 
         if remat:
             body = jax.checkpoint(body)
@@ -218,9 +226,10 @@ def lm_apply(params, cfg, tokens, *, modality_embeds=None, remat: bool = True,
             # scan-free variant for HLO cost probes (see benchmarks/roofline)
             for r in range(n_reps):
                 rep = jax.tree.map(lambda p: p[r], params["stack"])
-                (x, aux), _ = body((x, aux), rep)
+                (x, aux, stats), _ = body((x, aux, stats), rep)
         else:
-            (x, aux), _ = jax.lax.scan(body, (x, aux), params["stack"])
+            (x, aux, stats), _ = jax.lax.scan(body, (x, aux, stats),
+                                              params["stack"])
 
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if logits_mode == "last":
@@ -231,6 +240,8 @@ def lm_apply(params, cfg, tokens, *, modality_embeds=None, remat: bool = True,
         if enc_out is not None:
             caches["enc_out"] = enc_out
         return logits, aux, caches
+    if return_stats:
+        return logits, aux, stats
     return logits, aux
 
 

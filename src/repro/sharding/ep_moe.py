@@ -30,13 +30,15 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import mlp_apply
+from repro.models.moe import top_k_gates
 
 
-def _local_dispatch(xt, logits, E, K, capacity):
-    """Token->expert dispatch on one shard. xt: (T, d)."""
+def _local_dispatch(cfg, xt, logits, capacity):
+    """Token->expert dispatch on one shard. xt: (T, d). Gating is the
+    config's (`models/moe.top_k_gates`)."""
     T, d = xt.shape
-    gate_vals, expert_ids = jax.lax.top_k(logits, K)
-    gates = jax.nn.softmax(gate_vals, axis=-1)
+    E, K = cfg.num_experts, cfg.num_experts_per_tok
+    gates, expert_ids = top_k_gates(cfg, logits)
     flat_expert = expert_ids.reshape(-1)
     flat_token = jnp.repeat(jnp.arange(T), K)
     flat_gate = gates.reshape(-1)
@@ -77,7 +79,7 @@ def ep_moe_apply(params, cfg, x, mesh, *, capacity_factor=None):
         logits = (xt @ w_router).astype(jnp.float32)
         capacity = int(np.ceil(T * K / E * cf))
         x_e, (sorted_token, sorted_gate, keep, slot) = _local_dispatch(
-            xt, logits, E, K, capacity)
+            cfg, xt, logits, capacity)
         # all_to_all (tiled): (E, C, d) -> (E/mp, C*mp, d): expert axis
         # split across the model axis, token buckets concatenated at the
         # expert owner

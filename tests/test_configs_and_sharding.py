@@ -81,6 +81,43 @@ def test_ep_moe_matches_tp_single_device(rng):
                                rtol=2e-4, atol=2e-4)
 
 
+def test_ep_moe_softmax_gating_matches_tp_single_device(rng):
+    """A DeepSeek-gated config with moe_impl "ep" and a mesh set takes
+    the all-to-all path, not the held-expert layer, and gates it the
+    DeepSeek way: on a 1-device mesh it matches the TP capacity
+    dispatch under the same gating, and not under Mixtral's."""
+    from repro.launch.mesh import make_device_mesh
+    from repro.models import blocks
+    from repro.models import moe as tp_moe
+    from repro.models.layers import Rng
+    from repro.sharding.context import set_mesh
+    cfg = dataclasses.replace(
+        reduced_config(get_config("deepseek-v2-236b")), num_shared_experts=0,
+        moe_impl="ep")
+    assert cfg.router_scoring == "softmax"
+    params = tp_moe.moe_init(Rng(jax.random.PRNGKey(0)), cfg, jnp.float32)
+    x = jnp.asarray(rng.normal(0, 0.5, (2, 8, cfg.d_model)), jnp.float32)
+    y_tp, _aux = tp_moe.moe_apply(params, cfg, x)
+    set_mesh(make_device_mesh(jax.devices()[:1]))
+    try:
+        y_ep, _aux, stats = blocks._moe(params, cfg, x)
+    finally:
+        set_mesh(None)
+    assert stats == {}
+    np.testing.assert_allclose(np.asarray(y_ep), np.asarray(y_tp),
+                               rtol=2e-4, atol=2e-4)
+    y_mixtral, _aux = tp_moe.moe_apply(
+        params, dataclasses.replace(cfg, router_scoring="topk_softmax"), x)
+    assert not np.allclose(np.asarray(y_mixtral), np.asarray(y_tp),
+                           rtol=2e-2, atol=2e-2)
+
+
+def test_held_experts_exclude_the_expert_all_to_all():
+    with pytest.raises(AssertionError, match="all-to-all"):
+        dataclasses.replace(get_config("deepseek-v2-lite"), experts_held=8,
+                            moe_impl="ep")
+
+
 @pytest.mark.parametrize("kind", ["train", "prefill", "decode"])
 def test_launch_path_lowers_on_host_mesh(kind, rng):
     """input_specs + step builders lower on the 1-device host mesh for a

@@ -178,3 +178,30 @@ def test_decode_step_keeps_one_kv_cache(topo):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < cache_bytes / 4
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+@pytest.mark.parametrize("kind", ["gate_up", "down", "input_grad",
+                                  "weight_grad"])
+def test_expert_grouped_matmul(one_chip, kind):
+    """The held experts' grouped products at the deepseek-v2-lite cell's
+    shapes: 4096 tokens x 6 picks of rows over 8 experts of 1408 at
+    width 2048, in bfloat16, under the step's vmap over client groups
+    (the kernels alone; the whole step's compile takes 70-100 s)."""
+    from repro.kernels.grouped_matmul import ops as gmm_ops
+    m, d, f, E = 4096 * 6, 2048, 1408, 8
+    bf = jnp.bfloat16
+    sizes = ((1, E), jnp.int32)
+    if kind == "weight_grad":
+        fn = jax.vmap(lambda x, g, s: gmm_ops._run_tgmm(x, g, s, False))
+        shapes = (((1, m, d), bf), ((1, m, f), bf), sizes)
+    else:
+        k, n = {"gate_up": (d, f), "down": (f, d),
+                "input_grad": (d, f)}[kind]
+        transpose = kind == "input_grad"
+        w = (E, n, k) if transpose else (E, k, n)
+        fn = jax.vmap(lambda x, w, s: gmm_ops._run_gmm(x, w, s, False,
+                                                       transpose))
+        shapes = (((1, m, k), bf), ((1,) + w, bf), sizes)
+    text = _compile(fn, one_chip, *shapes)
+    name = "expert_tgmm" if kind == "weight_grad" else "expert_gmm"
+    assert f"%{name}" in text
