@@ -33,15 +33,24 @@ Two executions of the inner loop:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import dispatch
 from repro.kernels.meta_update import ops as mu_ops
 from repro.models.layers import Rng
 from repro.utils.flat import plane_for
+
+
+def _order_scope(order: int):
+    """What a client gradient traces in: second order differentiates
+    the inner gradient again, which kernels with a first-order backward
+    only cannot serve (``kernels/dispatch.second_order``)."""
+    return dispatch.second_order() if order == 2 else contextlib.nullcontext()
 
 
 def _inner_adapt(loss_fn, theta, alpha, support, steps: int,
@@ -200,8 +209,9 @@ class MAML(MetaAlgorithm):
             return self.eval_fn(theta_u, query)
 
         if self.order == 2:
-            (loss, metrics), g = jax.value_and_grad(meta_loss,
-                                                    has_aux=True)(phi["theta"])
+            with dispatch.second_order():
+                (loss, metrics), g = jax.value_and_grad(
+                    meta_loss, has_aux=True)(phi["theta"])
         else:
             # FOMAML: gradient at the adapted parameters
             theta_u = _inner_adapt(self.loss_fn, phi["theta"], self.inner_lr,
@@ -227,8 +237,9 @@ class MAML(MetaAlgorithm):
                 losses, mets = jax.vmap(flat_eval)(Theta_u, query)
                 return jnp.sum(losses), (losses, mets)
 
-            G, (losses, mets) = jax.grad(chunk_meta_loss,
-                                         has_aux=True)(Theta0)
+            with dispatch.second_order():
+                G, (losses, mets) = jax.grad(chunk_meta_loss,
+                                             has_aux=True)(Theta0)
         else:
             Theta_u = _inner_adapt_plane(
                 self.loss_fn, tplane, Theta0, self.inner_lr, support,
@@ -272,8 +283,9 @@ class MetaSGD(MetaAlgorithm):
                                    second_order=(self.order == 2))
             return self.eval_fn(theta_u, query)
 
-        (loss, metrics), g = jax.value_and_grad(meta_loss,
-                                                has_aux=True)(phi)
+        with _order_scope(self.order):
+            (loss, metrics), g = jax.value_and_grad(meta_loss,
+                                                    has_aux=True)(phi)
         return g, {"query_loss": loss, **metrics}
 
     def client_grad_chunk_packed(self, pplane, tplane, phi, support, query,
@@ -292,8 +304,10 @@ class MetaSGD(MetaAlgorithm):
             losses, mets = jax.vmap(flat_eval)(Theta_u, query)
             return jnp.sum(losses), (losses, mets)
 
-        (_, (losses, mets)), (gT, gA) = jax.value_and_grad(
-            chunk_meta_loss, argnums=(0, 1), has_aux=True)(Theta0, Alpha0)
+        with _order_scope(self.order):
+            (_, (losses, mets)), (gT, gA) = jax.value_and_grad(
+                chunk_meta_loss, argnums=(0, 1), has_aux=True)(Theta0,
+                                                               Alpha0)
         G = _assemble_phi_rows(pplane, tplane, {"theta": gT, "alpha": gA})
         return G, {"query_loss": losses, **mets}
 

@@ -23,7 +23,6 @@ from repro.configs import INPUT_SHAPES, InputShape, ModelConfig
 from repro.core.algorithms import make_algorithm
 from repro.core.fedmeta import federated_meta_step
 from repro.core.losses import lm_loss
-from repro.kernels.attention import ops as attn_ops
 from repro.kernels.meta_update import ops as mu_ops
 from repro.kernels.ssd import ops as ssd_ops
 from repro.models import init_lm, lm_apply, init_decode_cache, lm_decode_step
@@ -49,15 +48,17 @@ def make_apply_fn(cfg: ModelConfig, *, remat: bool = True,
     """apply(params, batch) -> (logits, aux); batch = tokens or dict.
 
     This is the forward of the LM loss, which training and adaptation
-    differentiate. The flash-attention and SSD kernels have no backward,
-    so attention and the SSD scan are pinned to their XLA paths here,
-    whatever the platform (serving's prefill and decode keep the
-    kernels). Where the MoE layers count their routing (DeepSeek
-    gating), apply returns (logits, aux, stats) and the LM loss reports
-    the counters."""
+    differentiate. Attention takes the platform's path: the flash
+    kernels have a first-order backward, and a step that differentiates
+    twice (second-order MAML, Meta-SGD) runs XLA attention by the
+    algorithm's ``dispatch.second_order`` scope. The SSD kernel has no
+    backward, so the SSD scan is pinned to XLA here, whatever the
+    platform (serving's prefill keeps the kernel). Where the MoE layers
+    count their routing (DeepSeek gating), apply returns (logits, aux,
+    stats) and the LM loss reports the counters."""
 
     def apply_fn(params, batch):
-        with attn_ops.use_impl("xla"), ssd_ops.use_impl("xla"):
+        with ssd_ops.use_impl("xla"):
             if isinstance(batch, dict):
                 tokens, embeds = batch["tokens"], batch.get("embeds")
             else:

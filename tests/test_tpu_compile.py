@@ -18,7 +18,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.kernels.attention.flash_attention import flash_attention_bhld
+from repro.kernels.attention.flash_attention import (Attn, block_sizes,
+                                                     flash_attention_bhld,
+                                                     flash_bwd_dkv,
+                                                     flash_bwd_dq, flash_fwd)
 from repro.kernels.decode_attention.flash_decode import flash_decode
 from repro.kernels.meta_update.aggregate import weighted_aggregate_flat
 from repro.kernels.meta_update.fused import inner_update_plane
@@ -131,12 +134,52 @@ def test_flash_decode(one_chip, vmapped):
 
 
 def test_flash_attention_forward(one_chip):
-    """smollm-360m prefill attention at 4096 tokens (forward only: the
-    kernel has no backward, so training pins XLA attention)."""
+    """smollm-360m prefill attention at 4096 tokens, the forward alone
+    (serving's prefill; training takes the kernels' VJP, below)."""
     cfg = get_config("smollm-360m")
     H, Kv, hd, L = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, 4096
-    _compile(flash_attention_bhld, one_chip, ((1, H, L, hd), jnp.bfloat16),
-             ((1, Kv, L, hd), jnp.bfloat16), ((1, Kv, L, hd), jnp.bfloat16))
+    _compile(flash_attention_bhld, one_chip,
+             ((1, H, L, hd), jnp.bfloat16), ((1, Kv, L, hd), jnp.bfloat16),
+             ((1, Kv, L, hd), jnp.bfloat16))
+
+
+def _attention_widths(model):
+    """(B, H, Kv, L, hd, hd_v, scale) of one training attention call."""
+    if model == "smollm":   # 15 query heads over 5 KV heads of 64
+        cfg = get_config("smollm-360m")
+        return (1, cfg.num_heads, cfg.num_kv_heads, 4096, cfg.head_dim,
+                cfg.head_dim, cfg.head_dim ** -0.5)
+    from repro.models.attention import mla_softmax_scale
+    cfg = get_config("deepseek-v2-lite")   # MLA, YaRN's softmax scale
+    hd = cfg.head_dim + cfg.rope_head_dim
+    return (2, cfg.num_heads, cfg.num_heads, 2048, hd, cfg.head_dim,
+            mla_softmax_scale(cfg))
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkv"])
+@pytest.mark.parametrize("model", ["smollm", "deepseek"])
+def test_flash_attention_training_kernels(one_chip, model, kernel):
+    """The three kernels of attention's custom VJP at the training
+    widths of the LM cells, at the blocks the wrapper chooses:
+    smollm-360m (1 x 15 heads over 5 x 4096 x 64) and deepseek-v2-lite
+    (2 x 16 x 2048, hd 192 / hd_v 128)."""
+    B, H, Kv, L, hd, hd_v, scale = _attention_widths(model)
+    bq, bk = block_sizes(L, L)
+    a = Attn(scale=scale, causal=True, window=None, q_offset=0,
+                block_q=bq, block_k=bk)
+    bf, f32 = jnp.bfloat16, jnp.float32
+    qkv = (((B, H, L, hd), bf), ((B, Kv, L, hd), bf), ((B, Kv, L, hd_v), bf))
+    if kernel == "flash_fwd":
+        text = _compile(lambda q, k, v: flash_fwd(q, k, v, a), one_chip,
+                        *qkv)
+    else:
+        fn = {"flash_bwd_dq": flash_bwd_dq, "flash_bwd_dkv": flash_bwd_dkv}[
+            kernel]
+        text = _compile(lambda *x: fn(*x, a), one_chip, *qkv,
+                        ((B, H, L, hd_v), bf), ((B, H, 1, L), f32),
+                        ((B, H, 1, L), f32))
+    assert f"%{kernel}" in text
 
 
 def test_decode_step_keeps_one_kv_cache(topo):
