@@ -37,7 +37,7 @@ def main():
                     help="fixed target accuracy (default: highest "
                          "accuracy every method reaches)")
     ap.add_argument("--pipeline", default="tree",
-                    choices=["tree", "packed", "client_plane"])
+                    choices=["tree", "client_plane"])
     ap.add_argument("--client-chunk", type=int, default=0)
     ap.add_argument("--prefetch-depth", type=int, default=0,
                     help="async round engine: staged round blocks ahead "
@@ -46,11 +46,12 @@ def main():
     ap.add_argument("--flush-every", type=int, default=1,
                     help="deferred-metrics drain cadence (0 = at exit)")
     ap.add_argument("--fuse-rounds", type=int, default=1,
-                    help="lax.scan round-block size (packed pipelines)")
+                    help="lax.scan round-block size (client_plane "
+                         "pipeline)")
     ap.add_argument("--aggregator", default="mean",
                     choices=["mean", "masked_mean", "screen", "trimmed"],
                     help="FedMeta (m, N) aggregation mode (DESIGN.md "
-                         "§14; non-mean needs a packed pipeline)")
+                         "§14; non-mean needs pipeline client_plane)")
     ap.add_argument("--fault-dropout", type=float, default=0.0,
                     help="fraction of each round's clients whose update "
                          "never arrives (fault injection)")
@@ -67,7 +68,7 @@ def main():
     ap.add_argument("--over-select", type=float, default=0.0,
                     help="sample m·(1+x) candidates per round, "
                          "aggregate the first m arrivals (FedMeta "
-                         "methods; needs a packed pipeline)")
+                         "methods; needs pipeline client_plane)")
     ap.add_argument("--round-deadline", type=float, default=0.0,
                     help="arrival latency cutoff, in unreliability "
                          "units (0 = no deadline)")
@@ -83,7 +84,7 @@ def main():
     ap.add_argument("--codec", default="",
                     choices=["", "int8", "topk"],
                     help="FedMeta upload compression (DESIGN.md §17; "
-                         "needs a packed pipeline)")
+                         "needs pipeline client_plane)")
     ap.add_argument("--topk-frac", type=float, default=0.05,
                     help="fraction of real parameters each client "
                          "transmits under --codec topk")

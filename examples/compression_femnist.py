@@ -64,7 +64,7 @@ def main():
         plan = default_plan(
             "femnist", methods=(METHOD,), rounds=rounds,
             eval_every=args.eval_every, num_clients=num_clients,
-            target_acc=target, pipeline="packed", seed=args.seed,
+            target_acc=target, pipeline="client_plane", seed=args.seed,
             name=f"compression_{label}", **knobs)
         out = run_comparison(plan, save=False, log=print)
         rec = out["methods"][METHOD]

@@ -36,7 +36,7 @@ def main():
     ap.add_argument("--ckpt", default="/tmp/fedmeta_femnist")
     ap.add_argument("--eval-every", type=int, default=50)
     ap.add_argument("--packed", action="store_true",
-                    help="run FedMeta on the packed parameter plane")
+                    help="run FedMeta on the flat pipeline (client plane)")
     ap.add_argument("--no-baseline", action="store_true",
                     help="skip the FedAvg baseline comparison")
     args = ap.parse_args()

@@ -18,8 +18,8 @@ rounds — to the shared target.
   # CI smoke (few rounds, tiny pools):
   PYTHONPATH=src python examples/table3_production.py --dry-run
 
-For the non-federated Table-3 baselines (MFU/MRU/NB/LR-self/NN-self and
-the unified fine-tuned NN), see ``benchmarks/table3_production.py``.
+The non-federated Table-3 baselines (MFU/MRU/NB/LR-self/NN-self and
+the unified fine-tuned NN) have no entry point in this repo.
 """
 import argparse
 
@@ -40,7 +40,7 @@ def main():
                     help="fixed target accuracy (default: highest "
                          "accuracy every method sustainably reaches)")
     ap.add_argument("--pipeline", default="tree",
-                    choices=["tree", "packed", "client_plane"])
+                    choices=["tree", "client_plane"])
     ap.add_argument("--prefetch-depth", type=int, default=0)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--outdir", default="results/experiments")

@@ -2,7 +2,7 @@
 
     PYTHONPATH=src python -m repro.analysis.lint --strict
 
-Lints ``src/ examples/ benchmarks/ tests/`` (or explicit paths) with
+Lints ``src/ examples/ tests/`` (or explicit paths) with
 the repo-specific rule families:
 
   rng-*      seeded-streams-only randomness
@@ -27,7 +27,7 @@ import sys
 from repro.analysis import core
 from repro.analysis.core import RULE_DOCS, lint_paths
 
-DEFAULT_PATHS = ("src", "examples", "benchmarks", "tests")
+DEFAULT_PATHS = ("src", "examples", "tests")
 DEFAULT_BASELINE = ".repro-lint-baseline.json"
 
 

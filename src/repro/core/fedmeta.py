@@ -27,13 +27,12 @@ Two parameter representations:
   - tree (default): φ stays a pytree; aggregation and the outer step run
     per-leaf,
   - packed plane (``make_packed_meta_train_step``): φ lives in one flat
-    128-lane-aligned f32 buffer (utils/flat.py); client gradients are
-    packed to an (m, N) block, reduced by the fused aggregation kernel,
-    and φ is advanced by the fused outer-Adam kernel — the whole server
-    side of the round is two passes over flat memory. With
-    ``client_plane=True`` the *client* half runs on flat memory too:
-    chunks of clients adapt in lockstep on a (C, N) plane with the
-    fused inner-update kernel (DESIGN.md §9).
+    128-lane-aligned f32 buffer (utils/flat.py). Chunks of clients adapt
+    in lockstep on a (C, N) client plane with the fused inner-update
+    kernel, their meta-gradients come out as rows of an (m, N) block,
+    the fused aggregation kernel reduces the block, and the fused
+    outer-Adam kernel advances φ: the whole round is flat except the
+    model forward/backward itself (DESIGN.md §9).
 """
 from __future__ import annotations
 
@@ -267,12 +266,43 @@ def init_packed_state(optimizer, plane: FlatPlane, phi, *, staleness=None,
     return state
 
 
+def check_plane_composition(client_axis: str = "vmap", *,
+                            aggregator: str = "mean", staleness=None,
+                            faults=None, compression=None, dp=None):
+    """Raise ``ValueError`` unless the packed step can build this
+    combination of planes. The staleness ring, the failure plane
+    (faults, a robust aggregator) and the bytes-on-the-wire plane
+    (compression, DP) each need the full (m, N) gradient block before
+    the reduce, so client_axis='vmap'; compression / DP exclude the
+    other two. ``make_packed_meta_train_step`` and ``FederatedTrainer``
+    both apply these rules; the trainer adds its own on top."""
+    if aggregator not in mu_ops.AGGREGATORS:
+        raise ValueError(f"unknown aggregator {aggregator!r}; expected "
+                         f"one of {mu_ops.AGGREGATORS}")
+    robust = aggregator != "mean"
+    wire = compression is not None or dp is not None
+    if client_axis != "vmap":
+        for name, on in (("staleness-aware aggregation",
+                          staleness is not None),
+                         ("fault injection / robust aggregation",
+                          faults is not None or robust),
+                         ("compression / DP", wire)):
+            if on:
+                raise ValueError(f"{name} needs the full (m, N) gradient "
+                                 f"block before the reduce — "
+                                 f"client_axis='vmap' only")
+    if wire and (staleness is not None or faults is not None or robust):
+        raise ValueError("compression / DP compose with each other but "
+                         "not with staleness, faults, or robust "
+                         "aggregators — the codec/clip semantics of ring "
+                         "rows and corrupted rows are undefined")
+
+
 def make_packed_meta_train_step(algo, optimizer, plane: FlatPlane, *,
                                 client_axis: str = "vmap",
                                 client_chunk: int | None = None,
                                 impl: str | None = None,
                                 block_dtype=None,
-                                client_plane: bool = False,
                                 staleness=None,
                                 aggregator: str = "mean",
                                 screen_factor: float = 3.0,
@@ -285,26 +315,23 @@ def make_packed_meta_train_step(algo, optimizer, plane: FlatPlane, *,
                                 jit: bool = True, donate: bool = True):
     """Meta-train step over the packed plane: state = {phi: (N,), opt}.
 
-    φ is unpacked to a pytree exactly once per round (the client model
-    needs structured parameters); everything after the per-client grads —
-    aggregation and the outer Adam — stays on flat buffers. ``impl``
-    picks xla / pallas / pallas_interpret for the fused kernels (None =
-    the platform's pick, ``kernels/dispatch.py``). ``block_dtype`` sets
-    the dtype of the packed client-gradient block (None = f32, exact;
-    bfloat16 halves the aggregation traffic and models a half-precision
-    client upload — the fused ops still accumulate in f32; see
-    DESIGN.md §2).
+    The inner loop runs on flat memory: each chunk of clients adapts in
+    lockstep on a (C, N) client plane via the fused inner-update kernel,
+    and per-client meta-gradients come out flat
+    (``algo.client_grad_chunk_packed``); aggregation and the outer Adam
+    stay on flat buffers too (DESIGN.md §9). ``impl`` picks xla / pallas
+    / pallas_interpret for the fused kernels (None = the platform's
+    pick, ``kernels/dispatch.py``). ``block_dtype`` sets the dtype of
+    the client-gradient block (None = f32, exact; bfloat16 halves the
+    aggregation traffic and models a half-precision client upload —
+    the fused ops still accumulate in f32; see DESIGN.md §2).
 
-    ``client_plane=True`` additionally runs the *inner loop* on flat
-    memory: each chunk of clients adapts in lockstep on a (C, N) client
-    plane via the fused inner-update kernel, and per-client
-    meta-gradients come out flat (``algo.client_grad_chunk_packed``) —
-    no per-client pytree pack, the whole round is flat end-to-end
-    except the model forward/backward itself (DESIGN.md §9).
     ``client_axis="sharded"`` splits clients over the devices of
     ``mesh`` (default: the ambient mesh); each device reduces its local
     block with the packed aggregation kernel and the (N,) partials are
-    psum-reduced into the meta-gradient (DESIGN.md §10).
+    psum-reduced into the meta-gradient (DESIGN.md §10). Which planes
+    below compose, and on which client axes, is decided by
+    ``check_plane_composition``.
 
     ``staleness`` (async_engine.StalenessConfig; vmap axis only) turns
     on staleness-aware aggregation: the step takes an extra
@@ -374,28 +401,10 @@ def make_packed_meta_train_step(algo, optimizer, plane: FlatPlane, *,
     impl = mu_ops.resolve_impl(impl)
     flat_opt = make_flat_optimizer(optimizer, impl=impl)
     bd = block_dtype or jnp.float32
-    if aggregator not in mu_ops.AGGREGATORS:
-        raise ValueError(f"unknown aggregator {aggregator!r}; expected "
-                         f"one of {mu_ops.AGGREGATORS}")
+    check_plane_composition(client_axis, aggregator=aggregator,
+                            staleness=staleness, faults=faults,
+                            compression=compression, dp=dp)
     robust = aggregator != "mean"
-    if staleness is not None and client_axis != "vmap":
-        raise ValueError("staleness-aware aggregation needs the full "
-                         "(m, N) gradient block before the reduce — "
-                         "client_axis='vmap' only")
-    if (faults is not None or robust) and client_axis != "vmap":
-        raise ValueError("fault injection / robust aggregation need the "
-                         "full (m, N) gradient block before the reduce — "
-                         "client_axis='vmap' only")
-    if compression is not None or dp is not None:
-        if client_axis != "vmap":
-            raise ValueError("compression / DP need the full (m, N) "
-                             "gradient block before the reduce — "
-                             "client_axis='vmap' only")
-        if staleness is not None or faults is not None or robust:
-            raise ValueError("compression / DP compose with each other "
-                             "but not with staleness, faults, or robust "
-                             "aggregators — the codec/clip semantics of "
-                             "ring rows and corrupted rows are undefined")
 
     def aggregate(G, w_agg, *, prenorm):
         """The (m, N) → (N,) reduce. ``prenorm`` marks the staleness
@@ -449,22 +458,14 @@ def make_packed_meta_train_step(algo, optimizer, plane: FlatPlane, *,
         m = jax.tree.leaves(support)[0].shape[0]
         w = _normalize_weights(weights, m)
 
-        if client_plane:
-            tplane = plane_for(phi["theta"])
+        tplane = plane_for(phi["theta"])
 
-            def chunk_grads(s, q):
-                """(C, N) gradient rows + metrics for a chunk of clients,
-                computed on the flat client plane."""
-                G, mets = algo.client_grad_chunk_packed(
-                    plane, tplane, phi, s, q, impl=impl)
-                return G.astype(bd), mets
-        else:
-            def one_packed(s, q):
-                g, met = algo.client_grad(phi, s, q)
-                return plane.pack(g, bd), met
-
-            def chunk_grads(s, q):
-                return jax.vmap(one_packed)(s, q)
+        def chunk_grads(s, q):
+            """(C, N) gradient rows + metrics for a chunk of clients,
+            computed on the flat client plane."""
+            G, mets = algo.client_grad_chunk_packed(
+                plane, tplane, phi, s, q, impl=impl)
+            return G.astype(bd), mets
 
         def packed_chunk(s, q, wc):
             """Fused (N,) weighted partial + weighted metrics for one
@@ -598,12 +599,9 @@ def make_packed_meta_train_step(algo, optimizer, plane: FlatPlane, *,
         elif client_axis == "scan":
             def body(acc, inp):
                 s, q, wi = inp
-                if client_plane:
-                    G, met = chunk_grads(
-                        *jax.tree.map(lambda x: x[None], (s, q)))
-                    g, met = G[0], jax.tree.map(lambda x: x[0], met)
-                else:
-                    g, met = one_packed(s, q)
+                G, met = chunk_grads(
+                    *jax.tree.map(lambda x: x[None], (s, q)))
+                g, met = G[0], jax.tree.map(lambda x: x[0], met)
                 return acc + wi * g.astype(jnp.float32), met
 
             meta_g, mets = jax.lax.scan(

@@ -59,6 +59,7 @@ from repro.federated.server import (FederatedTrainer, evaluate_global,
 
 FEDMETA_METHODS = ("maml", "fomaml", "meta-sgd", "reptile")
 FEDAVG_METHODS = ("fedavg", "fedavg(meta)")
+PIPELINES = ("tree", "client_plane")    # FedMeta: reference | flat
 DEFAULT_METHODS = FEDAVG_METHODS + ("maml", "fomaml", "meta-sgd")
 
 
@@ -166,11 +167,10 @@ def _lm_loss(model):
 
 
 # dataset name -> builders + paper-Table-4-shaped hyperparameters
-# (CPU-scaled, same values as benchmarks/table2_leaf.py). Like the
-# paper's Table 4, learning rates may be tuned per algorithm
-# (method_overrides) — the sharing discipline is about data splits,
-# sampling streams, and comm accounting, not about forcing one lr onto
-# algorithms with different update geometries.
+# (CPU-scaled). Like the paper's Table 4, learning rates may be tuned
+# per algorithm (method_overrides) — the sharing discipline is about
+# data splits, sampling streams, and comm accounting, not about forcing
+# one lr onto algorithms with different update geometries.
 #
 # Scenario extension points (all optional; DESIGN.md §13):
 #   loss        loss(model) -> (loss_fn, eval_fn); default
@@ -223,8 +223,9 @@ class ExperimentPlan:
     """Everything needed to reproduce one FedMeta-vs-FedAvg comparison.
 
     ``pipeline`` selects the FedMeta execution substrate: "tree" (pytree
-    φ), "packed" (flat parameter plane, PR 1) or "client_plane" (flat
-    inner loop too, PR 2) — the baselines are substrate-independent.
+    φ, the reference) or "client_plane" (the flat pipeline: φ, the inner
+    loop and the gradient block on flat memory) — the baselines are
+    substrate-independent.
     ``data_fn(num_clients, seed)`` / ``model_fn()`` / ``loss_builder
     (model)`` / ``meta_model_fn(plan)`` / ``meta_data_fn(clients, plan)``
     override the named registry for custom scenarios (callables are not
@@ -257,7 +258,7 @@ class ExperimentPlan:
     # consecutive evals — single-eval noise spikes must not set the
     # comm-to-target table (charged at the window's last round)
     sustain_evals: int = 2
-    pipeline: str = "tree"               # tree | packed | client_plane
+    pipeline: str = "tree"               # tree | client_plane
     client_chunk: Optional[int] = None
     # async round engine (DESIGN.md §12): staged round blocks ahead of
     # the device (0 = the synchronous loop) and the deferred-metrics
@@ -270,7 +271,7 @@ class ExperimentPlan:
     # failure plane (DESIGN.md §14): FedMeta (m, N) aggregation mode and
     # optional per-round client-failure injection. Applies to the
     # FedMeta methods only (the FedAvg baselines have no (m, N) gradient
-    # plane); requires pipeline="packed"/"client_plane". The faults
+    # plane); requires pipeline="client_plane". The faults
     # config is a frozen dataclass and serializes into the artifact, so
     # a robustness sweep's JSON records its exact failure model.
     aggregator: str = "mean"             # mean|masked_mean|screen|trimmed
@@ -297,7 +298,7 @@ class ExperimentPlan:
     pool_workers: int = 0
     # bytes-on-the-wire plane (DESIGN.md §17): upload compression +
     # central DP for the FedMeta methods (they need the (m, N) gradient
-    # plane, like faults — pipeline="packed"/"client_plane" only; the
+    # plane, like faults — pipeline="client_plane" only; the
     # FedAvg baselines ship dense full models by construction).
     # ``block_dtype``/``opt_state_dtype`` are dtype NAMES ("bfloat16")
     # so plans stay JSON-serializable: the gradient-block wire dtype
@@ -364,6 +365,9 @@ def make_trainer(plan: ExperimentPlan, method: str, loss_fn, eval_fn,
         state = tr.run(state, plan.rounds, eval_every=plan.eval_every,
                        eval_clients=val_clients)
     """
+    if plan.pipeline not in PIPELINES:
+        raise ValueError(f"unknown pipeline {plan.pipeline!r}; expected "
+                         f"one of {PIPELINES}")
     common = dict(clients_per_round=plan.clients_per_round,
                   support_frac=plan.support_frac,
                   support_size=plan.support_size,
@@ -384,46 +388,26 @@ def make_trainer(plan: ExperimentPlan, method: str, loss_fn, eval_fn,
                           inner_lr=over.get("inner_lr", plan.inner_lr),
                           inner_steps=over.get("inner_steps",
                                                plan.inner_steps))
-    packed = plan.pipeline in ("packed", "client_plane")
-    if (plan.faults is not None or plan.aggregator != "mean") and not packed:
-        raise ValueError("plan.faults / plan.aggregator need the packed "
-                         "pipeline — set pipeline='packed' or "
-                         "'client_plane'")
-    if (plan.compression is not None or plan.dp is not None
-            or plan.block_dtype) and not packed:
-        raise ValueError("plan.compression / plan.dp / plan.block_dtype "
-                         "need the packed pipeline — set pipeline="
-                         "'packed' or 'client_plane'")
     import jax.numpy as jnp
     opt_kw = {}
     if plan.opt_state_dtype:
         # quantized optimizer state (§17): fused Adam keeps m/v in this
         # dtype and dequantizes inside the kernel (the olmax trick)
         opt_kw["state_dtype"] = jnp.dtype(plan.opt_state_dtype)
-    pop = {}
-    if (plan.unreliability is not None or plan.over_select
-            or plan.round_deadline is not None or plan.pool_workers):
-        if (plan.unreliability is not None or plan.over_select
-                or plan.round_deadline is not None) and not packed:
-            raise ValueError("plan.unreliability / over_select / "
-                             "round_deadline need the packed pipeline — "
-                             "set pipeline='packed' or 'client_plane'")
-        pop = dict(unreliability=plan.unreliability,
-                   over_select=plan.over_select,
-                   round_deadline=plan.round_deadline,
-                   pool_workers=plan.pool_workers)
     return FederatedTrainer(
         algo, adam(over.get("outer_lr", plan.outer_lr), **opt_kw),
         train_clients,
         client_axis="chunked" if plan.client_chunk else "vmap",
-        client_chunk=plan.client_chunk, packed=packed,
-        client_plane=(plan.pipeline == "client_plane"),
+        client_chunk=plan.client_chunk,
+        packed=(plan.pipeline == "client_plane"),
         block_dtype=(jnp.dtype(plan.block_dtype)
                      if plan.block_dtype else None),
         compression=plan.compression, dp=plan.dp,
-        fuse_rounds=plan.fuse_rounds if packed else 1,
-        aggregator=plan.aggregator, screen_factor=plan.screen_factor,
-        trim=plan.trim, faults=plan.faults, **pop, **common)
+        fuse_rounds=plan.fuse_rounds, aggregator=plan.aggregator,
+        screen_factor=plan.screen_factor, trim=plan.trim, faults=plan.faults,
+        unreliability=plan.unreliability, over_select=plan.over_select,
+        round_deadline=plan.round_deadline, pool_workers=plan.pool_workers,
+        **common)
 
 
 @dataclasses.dataclass

@@ -15,7 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.fedmeta import (_maybe_jit, _resolve_mesh, init_packed_state,
+from repro.core.fedmeta import (_maybe_jit, _resolve_mesh,
+                                check_plane_composition, init_packed_state,
                                 make_meta_train_step,
                                 make_packed_meta_train_step)
 from repro.data.federated import (TaskStream, assemble_task_batch,
@@ -28,7 +29,6 @@ from repro.federated.privacy import DPConfig
 from repro.kernels.meta_update.compress import CompressionConfig
 from repro.federated.population import (CircuitBreaker, UnreliabilityConfig,
                                         plan_round)
-from repro.kernels.meta_update import ops as mu_ops
 from repro.optim import Optimizer
 from repro.utils.flat import plane_for
 from repro.utils.trace import span
@@ -146,10 +146,9 @@ class FederatedTrainer:
     client_axis: str = "vmap"
     seed: int = 0
     client_chunk: Optional[int] = None   # for client_axis="chunked"
-    packed: bool = False                 # packed parameter plane pipeline
+    packed: bool = False                 # the flat pipeline (client plane)
     impl: Optional[str] = None           # fused-kernel impl for packed
     block_dtype: Optional[object] = None  # client-grad block dtype (packed)
-    client_plane: bool = False  # fused flat inner loop (packed only)
     mesh: Optional[object] = None  # for client_axis="sharded" (None =
     mesh_axis: Optional[str] = None  # ambient mesh, first axis)
     # ---- async round engine (DESIGN.md §12) -------------------------
@@ -183,27 +182,35 @@ class FederatedTrainer:
     breaker_cooldown: int = 10  # quarantine length in rounds
 
     def __post_init__(self):
-        if self.client_plane and not self.packed:
-            raise ValueError("client_plane=True requires packed=True")
-        if self.fuse_rounds > 1 and not self.packed:
-            raise ValueError("fuse_rounds>1 (fused-K round blocks) is a "
-                             "packed-pipeline mode")
-        if self.staleness is not None:
-            if not self.packed or self.client_axis != "vmap":
-                raise ValueError("staleness-aware aggregation requires "
-                                 "packed=True and client_axis='vmap'")
-            if self.fuse_rounds > 1:
-                raise ValueError("staleness and fuse_rounds>1 are mutually "
-                                 "exclusive (stragglers need per-round "
-                                 "straggler picks)")
         if self.over_select < 0:
             raise ValueError("over_select must be >= 0")
-        if self._population_active:
-            if not self.packed or self.client_axis != "vmap":
+        check_plane_composition(
+            self.client_axis, aggregator=self.aggregator,
+            staleness=self.staleness, faults=self.faults,
+            compression=self.compression, dp=self.dp)
+        pop = self._population_active
+        if not self.packed:
+            flat_only = {
+                "the population plane (unreliability / over_select / "
+                "round_deadline)": pop,
+                "fuse_rounds>1": self.fuse_rounds > 1,
+                "staleness": self.staleness is not None,
+                "faults": self.faults is not None,
+                f"aggregator={self.aggregator!r}": self.aggregator != "mean",
+                "compression": self.compression is not None,
+                "DP": self.dp is not None,
+                "the non-finite guard": bool(self.guard),
+                "block_dtype": self.block_dtype is not None}
+            on = [name for name, used in flat_only.items() if used]
+            if on:
+                raise ValueError(f"these modes run on the flat pipeline "
+                                 f"only (packed=True): {', '.join(on)}")
+        if pop:
+            if self.client_axis != "vmap":
                 raise ValueError("the population plane (unreliability / "
                                  "over_select / round_deadline) needs "
                                  "the full (m, N) client block — "
-                                 "packed=True and client_axis='vmap'")
+                                 "client_axis='vmap'")
             if self.fuse_rounds > 1:
                 raise ValueError("the population plane and fuse_rounds>1 "
                                  "are mutually exclusive (arrival plans "
@@ -213,49 +220,31 @@ class FederatedTrainer:
                                  "plane are mutually exclusive — the "
                                  "deadline model already decides who "
                                  "arrives late")
+            if self.compression is not None or self.dp is not None:
+                raise ValueError("compression / DP and the population "
+                                 "plane are mutually exclusive")
             if self.aggregator == "mean":
                 # partial rounds need the renormalizing aggregator:
                 # zero-weight pad rows must be exact no-ops
                 self.aggregator = "masked_mean"
-        if self.aggregator not in mu_ops.AGGREGATORS:
-            raise ValueError(f"unknown aggregator {self.aggregator!r}; "
-                             f"expected one of {mu_ops.AGGREGATORS}")
-        if self.faults is not None or self.aggregator != "mean":
-            if not self.packed or self.client_axis != "vmap":
-                raise ValueError("fault injection / robust aggregation "
-                                 "need the full (m, N) client block — "
-                                 "packed=True and client_axis='vmap'")
-        if self.faults is not None and self.fuse_rounds > 1:
-            raise ValueError("faults and fuse_rounds>1 are mutually "
-                             "exclusive (failures need per-round picks)")
+        if self.fuse_rounds > 1:
+            for name, on in (("staleness", self.staleness is not None),
+                             ("faults", self.faults is not None),
+                             ("compression / DP", self.compression
+                              is not None or self.dp is not None)):
+                if on:
+                    raise ValueError(f"{name} and fuse_rounds>1 are "
+                                     f"mutually exclusive (each round "
+                                     f"takes its own inputs)")
         if self.aggregator == "trimmed" and \
                 2 * self.trim >= self.clients_per_round:
             raise ValueError(f"trimmed mean needs 2·trim < clients_per_"
                              f"round ({self.trim} vs "
                              f"{self.clients_per_round})")
-        if self.compression is not None or self.dp is not None:
-            if not self.packed or self.client_axis != "vmap":
-                raise ValueError("compression / DP need the full (m, N) "
-                                 "client block — packed=True and "
-                                 "client_axis='vmap'")
-            if (self.staleness is not None or self.faults is not None
-                    or self.aggregator != "mean"
-                    or self._population_active):
-                raise ValueError("compression / DP compose with each "
-                                 "other but not with staleness, faults, "
-                                 "robust aggregators, or the population "
-                                 "plane")
-            if self.fuse_rounds > 1:
-                raise ValueError("compression / DP and fuse_rounds>1 are "
-                                 "mutually exclusive (EF indices and "
-                                 "noise keys are per-round inputs)")
         if self.guard is None:
             # auto: any failure-plane knob needs skip-round semantics
             self.guard = (self.faults is not None or
                           self.aggregator != "mean")
-        if self.guard and not self.packed:
-            raise ValueError("the non-finite guard is a flat-plane check "
-                             "— packed=True only")
         # the packed step needs φ's FlatPlane, built in init(); the tree
         # step has no such dependency and is built eagerly
         self._step = None if self.packed else make_meta_train_step(
@@ -294,7 +283,6 @@ class FederatedTrainer:
             kw = dict(client_axis=self.client_axis,
                       client_chunk=self.client_chunk, impl=self.impl,
                       block_dtype=self.block_dtype,
-                      client_plane=self.client_plane,
                       staleness=self.staleness,
                       aggregator=self.aggregator,
                       screen_factor=self.screen_factor, trim=self.trim,

@@ -31,11 +31,10 @@ from repro.models import init_lm
 from repro.sharding.rules import param_pspecs
 
 
-def build_serving_fns(cfg, *, unroll_layers: bool = False):
+def build_serving_fns(cfg):
     """(prefill, decode) entry points for `ServingEngine` — the same
     builders the dry-run lowers at production scale."""
-    return (make_prefill_step(cfg, unroll_layers=unroll_layers),
-            make_decode_step(cfg, unroll_layers=unroll_layers))
+    return make_prefill_step(cfg), make_decode_step(cfg)
 
 
 def build_engine(cfg, phi=None, *, algo_name: str = "fomaml",

@@ -43,8 +43,7 @@ def resolve_serving_config(cfg: ModelConfig, shape: InputShape) -> ModelConfig:
     return cfg
 
 
-def make_apply_fn(cfg: ModelConfig, *, remat: bool = True,
-                  unroll_layers: bool = False):
+def make_apply_fn(cfg: ModelConfig, *, remat: bool = True):
     """apply(params, batch) -> (logits, aux); batch = tokens or dict.
 
     This is the forward of the LM loss, which training and adaptation
@@ -65,7 +64,7 @@ def make_apply_fn(cfg: ModelConfig, *, remat: bool = True,
                 tokens, embeds = batch, None
             logits, aux, stats = lm_apply(
                 params, cfg, tokens, modality_embeds=embeds, remat=remat,
-                unroll_layers=unroll_layers, return_stats=True)
+                return_stats=True)
             return (logits, aux, stats) if stats else (logits, aux)
 
     return apply_fn
@@ -88,18 +87,14 @@ def reduce_metrics(mets, axis: int = 0):
 def make_train_step(cfg: ModelConfig, *, algo_name: str = "fomaml",
                     inner_lr: float = 0.01, outer_lr: float = 1e-4,
                     inner_steps: int = 1, remat: bool = True,
-                    scan_clients: bool = True, unroll_layers: bool = False,
                     opt_state_dtype="float32"):
     """FedMeta meta-training step for an LM arch.
 
     state = {"phi": {...}, "opt": {...}}
     batch = {"support": leaf(G, C, S, ...), "query": ...} — G client groups
     (pod-parallel), C clients (scanned), S sequences (data-parallel).
-    scan_clients=False / unroll_layers=True produce scan-free HLO for the
-    roofline cost probes (XLA cost analysis counts loop bodies once).
     """
-    loss_fn, eval_fn = lm_loss(make_apply_fn(cfg, remat=remat,
-                                             unroll_layers=unroll_layers))
+    loss_fn, eval_fn = lm_loss(make_apply_fn(cfg, remat=remat))
     algo = make_algorithm(algo_name, loss_fn, eval_fn, inner_lr, inner_steps)
     optimizer = adam(outer_lr, state_dtype=jnp.dtype(opt_state_dtype))
 
@@ -133,15 +128,7 @@ def make_train_step(cfg: ModelConfig, *, algo_name: str = "fomaml",
                                 state["phi"] if algo_name.startswith("meta-sgd")
                                 else {"theta": state["phi"]["theta"]})
             C = jax.tree.leaves(sup)[0].shape[0]
-            if scan_clients:
-                meta_g, mets = jax.lax.scan(body, acc0, (sup, qry))
-            else:   # scan-free variant for cost probes
-                meta_g, mets_list = acc0, []
-                for i in range(C):
-                    sq = jax.tree.map(lambda x: x[i], (sup, qry))
-                    meta_g, met = body(meta_g, sq)
-                    mets_list.append(met)
-                mets = jax.tree.map(lambda *xs: jnp.stack(xs), *mets_list)
+            meta_g, mets = jax.lax.scan(body, acc0, (sup, qry))
             meta_g = jax.tree.map(lambda x: x / C, meta_g)
             return meta_g, reduce_metrics(mets)
 
@@ -156,23 +143,21 @@ def make_train_step(cfg: ModelConfig, *, algo_name: str = "fomaml",
 
 # ------------------------------------------------------------ serve steps
 
-def make_prefill_step(cfg: ModelConfig, *, unroll_layers: bool = False):
+def make_prefill_step(cfg: ModelConfig):
     def prefill_step(params, batch):
         tokens = batch["tokens"] if isinstance(batch, dict) else batch
         embeds = batch.get("embeds") if isinstance(batch, dict) else None
         logits, aux, cache = lm_apply(params, cfg, tokens,
                                       modality_embeds=embeds, remat=False,
-                                      collect_cache=True, logits_mode="last",
-                                      unroll_layers=unroll_layers)
+                                      collect_cache=True, logits_mode="last")
         return logits[:, 0], cache
 
     return prefill_step
 
 
-def make_decode_step(cfg: ModelConfig, *, unroll_layers: bool = False):
+def make_decode_step(cfg: ModelConfig):
     def decode_step(params, cache, tokens):
-        logits, new_cache = lm_decode_step(params, cfg, tokens, cache,
-                                           unroll_layers=unroll_layers)
+        logits, new_cache = lm_decode_step(params, cfg, tokens, cache)
         return logits[:, 0], new_cache
 
     return decode_step

@@ -144,8 +144,7 @@ def _logits(params, cfg, x):
 
 def lm_apply(params, cfg, tokens, *, modality_embeds=None, remat: bool = True,
              collect_cache: bool = False, cache_capacity: int | None = None,
-             logits_mode: str = "all", unroll_layers: bool = False,
-             return_stats: bool = False):
+             logits_mode: str = "all", return_stats: bool = False):
     """Training / prefill forward.
 
     tokens: (B, L_text) int32. modality_embeds: (B, n_mod, d_model) for
@@ -157,7 +156,7 @@ def lm_apply(params, cfg, tokens, *, modality_embeds=None, remat: bool = True,
     text positions for loss.
     """
     B, L_text = tokens.shape
-    lead, period, n_reps = layer_groups(cfg)
+    lead, period, _ = layer_groups(cfg)
     x = jnp.take(params["embed"], tokens, axis=0)
     enc_out = None
     n_mod = 0
@@ -198,16 +197,8 @@ def lm_apply(params, cfg, tokens, *, modality_embeds=None, remat: bool = True,
                 acc = acc + a
             return (h, acc), rep_caches
 
-        if unroll_layers:
-            outs = []
-            for r in range(n_reps):
-                rep = jax.tree.map(lambda p: p[r], params["stack"])
-                (x, aux), rc = body((x, aux), rep)
-                outs.append(rc)
-            stack_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-        else:
-            (x, aux), stack_caches = jax.lax.scan(body, (x, aux),
-                                                  params["stack"])
+        (x, aux), stack_caches = jax.lax.scan(body, (x, aux),
+                                              params["stack"])
         caches["stack"] = stack_caches
     else:
         def body(carry, rep_params):
@@ -222,14 +213,8 @@ def lm_apply(params, cfg, tokens, *, modality_embeds=None, remat: bool = True,
 
         if remat:
             body = jax.checkpoint(body)
-        if unroll_layers:
-            # scan-free variant for HLO cost probes (see benchmarks/roofline)
-            for r in range(n_reps):
-                rep = jax.tree.map(lambda p: p[r], params["stack"])
-                (x, aux, stats), _ = body((x, aux, stats), rep)
-        else:
-            (x, aux, stats), _ = jax.lax.scan(body, (x, aux, stats),
-                                              params["stack"])
+        (x, aux, stats), _ = jax.lax.scan(body, (x, aux, stats),
+                                          params["stack"])
 
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if logits_mode == "last":
@@ -270,7 +255,7 @@ def init_decode_cache(cfg, batch: int, capacity: int, dtype=None,
     return caches
 
 
-def lm_decode_step(params, cfg, tokens, cache, *, unroll_layers: bool = False):
+def lm_decode_step(params, cfg, tokens, cache):
     """One-token decode. tokens: (B, 1) int32. Returns (logits, cache)."""
     lead, period, n_reps = layer_groups(cfg)
     x = jnp.take(params["embed"], tokens, axis=0)
@@ -305,12 +290,7 @@ def lm_decode_step(params, cfg, tokens, cache, *, unroll_layers: bool = False):
                 stack[f"pos{j}"], layer)}
         return h, stack
 
-    carry = (x, cache["stack"])
-    if unroll_layers:
-        for r in range(n_reps):
-            carry = body(r, carry)
-    else:
-        carry = jax.lax.fori_loop(0, n_reps, body, carry)
-    x, new_cache["stack"] = carry
+    x, new_cache["stack"] = jax.lax.fori_loop(0, n_reps, body,
+                                              (x, cache["stack"]))
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     return _logits(params, cfg, x), new_cache

