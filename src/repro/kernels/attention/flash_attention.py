@@ -24,6 +24,13 @@ Three kernels, named for the profiler:
 `flash_attention_bhld` joins them under a custom VJP. Its backward is
 first order: a step that differentiates the loss twice (MAML,
 Meta-SGD) runs attention on XLA (``kernels/dispatch.second_order``).
+
+The forward names its two residuals with ``checkpoint_name``: the
+output (``RESIDUAL_O``) and the log-sum-exp (``RESIDUAL_LSE``). A
+rematerialized layer whose policy saves these names (the LM layer
+scan's) keeps them instead of running ``flash_fwd`` again in its
+backward, and recomputes only q, k and v, which cost a norm, the
+projections and RoPE. Outside such a policy the names change nothing.
 """
 from __future__ import annotations
 
@@ -32,11 +39,16 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128
+
+# The forward's residuals as a remat policy sees them (module docstring)
+RESIDUAL_O = "flash_attention.o"
+RESIDUAL_LSE = "flash_attention.lse"
 
 # Rows of q and of k/v a grid step takes, at most. The chip sweep in
 # PERF.md §6 (blocks 128-1024 for each kernel, at hd 64 and at hd 192 /
@@ -415,6 +427,8 @@ def _attention(q, k, v, a: Attn):
 
 def _attention_fwd(q, k, v, a):
     o, lse = flash_fwd(q, k, v, a)
+    o = checkpoint_name(o, RESIDUAL_O)
+    lse = checkpoint_name(lse, RESIDUAL_LSE)
     return o, (q, k, v, o, lse)
 
 

@@ -15,6 +15,14 @@ Entry points:
   lm_apply           training / prefill forward (optionally emits cache)
   init_decode_cache  decode cache pytree
   lm_decode_step     one-token decode against the cache
+
+With remat, the training forward's layer scan recomputes each layer in
+the backward but saves the flash-attention forward's output and
+log-sum-exp (``flash_attention.RESIDUAL_O``, ``RESIDUAL_LSE``): they
+are all the kernels' backward needs beyond q, k and v, and they come
+from the costliest op per FLOP in the layer, where q, k and v cost a
+norm, the projections and RoPE. Where attention runs XLA (second
+order) those names never appear and the remat saves nothing.
 """
 from __future__ import annotations
 
@@ -23,6 +31,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.attention.flash_attention import (RESIDUAL_LSE,
+                                                     RESIDUAL_O)
 from repro.models.blocks import (block_decode, block_forward, block_init,
                                  block_init_cache, block_prefill, layer_spec)
 from repro.models.moe import merge_stats, zero_stats
@@ -153,7 +163,8 @@ def lm_apply(params, cfg, tokens, *, modality_embeds=None, remat: bool = True,
     (logits, aux_loss, stats): the MoE layers' routing counters
     (`models/moe.STATS`; {} for configs that count none). For vlm,
     logits cover the full [prefix|text] sequence; the caller slices
-    text positions for loss.
+    text positions for loss. remat recomputes each scanned layer in the
+    backward, keeping only attention's residuals (module docstring).
     """
     B, L_text = tokens.shape
     lead, period, _ = layer_groups(cfg)
@@ -211,8 +222,10 @@ def lm_apply(params, cfg, tokens, *, modality_embeds=None, remat: bool = True,
             h = _maybe_shard_seq(cfg, h)
             return (h, acc, st), None
 
-        if remat:
-            body = jax.checkpoint(body)
+        if remat:   # keep attention's residuals, recompute the rest
+            body = jax.checkpoint(
+                body, policy=jax.checkpoint_policies.save_only_these_names(
+                    RESIDUAL_O, RESIDUAL_LSE))
         (x, aux, stats), _ = jax.lax.scan(body, (x, aux, stats),
                                           params["stack"])
 
